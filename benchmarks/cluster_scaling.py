@@ -8,6 +8,11 @@ per-worker stage breakdowns parsed from each worker's
 ``--metrics-out`` file.  Results land in
 ``results/bench/BENCH_cluster.json`` under the schema-v2 envelope.
 
+This process touches no JAX until both runs have exited (the envelope's
+device fields are read last): on a TPU host the chip belongs to one
+process at a time, and the runs' children need it.  There, the 2-worker
+run needs two chips (the planner refuses more workers than chips).
+
 Both runs pay the same per-process jax import + jit compile tax, so
 the headline ``speedup`` is honest about coordination overhead — on a
 shared/oversubscribed CPU it can sit below 1; the per-worker stage

@@ -6,9 +6,9 @@ Every table module exposes ``run(fast: bool) -> list[dict]`` with keys
 
 ``emit_bench`` writes the suites that CI trends across PRs
 (``BENCH_*.json``) in the unified envelope from ``repro.obs.metrics``:
-``{schema_version, suite, created_unix, env: {git_sha, host, device,
-...}, metrics: <payload>}`` — readers take the payload from
-``["metrics"]`` and the provenance from ``["env"]``.
+``{schema_version, suite, created_unix, env: {git_sha, host, platform,
+device_kind, device_count, ...}, metrics: <payload>}`` — readers take
+the payload from ``["metrics"]`` and the provenance from ``["env"]``.
 """
 from __future__ import annotations
 

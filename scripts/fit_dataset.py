@@ -74,6 +74,10 @@ def main(argv=None) -> int:
     ap.add_argument("--jax-profile", default=None, metavar="DIR",
                     help="capture a jax.profiler device trace into DIR")
     args = ap.parse_args(argv)
+    if argv is None:
+        # a command-line run keeps its compiled programs across runs
+        from repro.utils import use_compile_cache
+        use_compile_cache()
 
     from repro.core import fit_engine
     from repro.datastream.fitsource import DatasetFitSource

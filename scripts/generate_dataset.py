@@ -58,20 +58,21 @@ def worker_path(path: str, worker_id: int) -> str:
     return f"{root}.w{int(worker_id)}{ext}"
 
 
-def worker_flags(args, worker_id: int, num_workers: int) -> list:
-    """Rebuild the CLI flags for one spawned worker stripe from the
-    coordinator's parsed args.  Everything byte-relevant (fit, seed,
-    shard size, mode, backend, dtype) passes through unchanged; the
-    stripe is selected by ``--num-workers/--worker-id``; per-worker
-    artifacts (trace, metrics) keep the parent's flag and are
-    namespaced by the worker itself."""
+def worker_flags(args, worker_id, num_workers: int) -> list:
+    """Rebuild the CLI flags for one spawned worker stripe (or, with
+    ``worker_id=None``, for the planning child) from the coordinator's
+    parsed args.  Everything byte-relevant (fit, seed, shard size, mode,
+    backend, dtype) passes through unchanged; the stripe is selected by
+    ``--num-workers/--worker-id``; per-worker artifacts (trace, metrics)
+    keep the parent's flag and are namespaced by the worker itself."""
     flags = ["--fit", args.fit, "--out", args.out,
              "--shard-edges", args.shard_edges,
              "--seed", str(args.seed), "--mode", args.mode,
              "--num-workers", str(num_workers),
-             "--worker-id", str(worker_id),
              "--pipeline-depth", str(args.pipeline_depth),
              "--host-workers", str(args.host_workers)]
+    if worker_id is not None:
+        flags += ["--worker-id", str(worker_id)]
     if args.edges:
         flags += ["--edges", args.edges]
     if args.k_pref is not None:
@@ -96,22 +97,54 @@ def worker_flags(args, worker_id: int, num_workers: int) -> list:
     return flags
 
 
-def run_cluster(args, job) -> int:
-    """Coordinator mode: plan once, stripe across ``--num-workers``
-    spawned processes, merge journals into the one manifest."""
-    from repro.datastream import Manifest, ShardedGraphDataset
-    from repro.distributed.cluster import ClusterCoordinator, ClusterError
+def plan_only(args, job) -> int:
+    """``--plan-only``: write the plan (or, with ``--resume``, validate
+    the existing one) and exit.  A chip belongs to one process at a
+    time, so on a TPU host ``--num-workers`` above the host's chip count
+    is refused before any worker starts."""
+    import jax
 
-    if args.resume and Manifest.exists(args.out):
-        job._load_validated()      # refuse resumes that change streams
-    else:
-        try:
+    from repro.datastream import Manifest
+
+    chips = jax.local_device_count()
+    if args.num_workers and jax.default_backend() == "tpu" \
+            and args.num_workers > chips:
+        raise SystemExit(
+            f"error: --num-workers {args.num_workers} exceeds the {chips} "
+            "TPU chip(s) of this host — each worker process needs a chip "
+            "of its own; run at most one worker per chip")
+    try:
+        if args.resume and Manifest.exists(args.out):
+            job._load_validated()  # refuse resumes that change streams
+        else:
             job.plan(overwrite=args.resume)
-        except FileExistsError:
-            raise SystemExit(
-                f"error: {args.out} already holds a dataset — pass "
-                "--resume to continue it, or choose a different --out")
+    except FileExistsError:
+        raise SystemExit(
+            f"error: {args.out} already holds a dataset — pass "
+            "--resume to continue it, or choose a different --out")
+    except ValueError as e:
+        raise SystemExit(f"error: {e}")
+    return 0
+
+
+def run_cluster(args) -> int:
+    """Coordinator mode: plan in a child process, stripe across
+    ``--num-workers`` spawned processes, merge journals into the one
+    manifest.  This process initializes no JAX backend: a chip belongs
+    to one process at a time, and the planner and workers need it."""
+    import subprocess
+
+    from repro.datastream import ShardedGraphDataset
+    from repro.distributed.cluster import ClusterCoordinator, ClusterError
+    from repro.distributed.launcher import child_env
+
     script = os.path.abspath(__file__)
+    planner = ([sys.executable, script, "--plan-only"]
+               + worker_flags(args, None, args.num_workers)
+               + (["--resume"] if args.resume else []))
+    rc = subprocess.run(planner, env=child_env()).returncode
+    if rc != 0:
+        return rc
     coord = ClusterCoordinator(
         args.out,
         lambda w, W: [sys.executable, script] + worker_flags(args, w, W),
@@ -197,6 +230,11 @@ def main(argv=None) -> int:
                          "completions to journal.w{k}.jsonl and never "
                          "rewrites manifest.json — the building block "
                          "the cluster coordinator spawns")
+    ap.add_argument("--plan-only", action="store_true",
+                    help="write the plan (with --resume: validate the "
+                         "existing one) and exit without generating; "
+                         "the coordinator runs this as a child so that "
+                         "it never takes a chip itself")
     ap.add_argument("--max-shards", type=int, default=None,
                     help="stop after N shards (incremental progress)")
     ap.add_argument("--resume", action="store_true",
@@ -242,6 +280,10 @@ def main(argv=None) -> int:
                     help="additionally capture a jax.profiler device "
                          "trace into DIR (TensorBoard/Perfetto)")
     args = ap.parse_args(argv)
+    if argv is None:
+        # a command-line run keeps its compiled programs across runs
+        from repro.utils import use_compile_cache
+        use_compile_cache()
     if args.worker_id is not None and args.num_workers is None:
         ap.error("--worker-id needs --num-workers (the stripe count "
                  "the plan was made for)")
@@ -264,13 +306,15 @@ def main(argv=None) -> int:
         write_bench
 
     fit = build_fit(args)
+    if args.num_workers is not None and args.worker_id is None \
+            and not args.plan_only:
+        return run_cluster(args)
     tracer = Tracer()
     metrics = MetricsRegistry()
-    coordinator = args.num_workers is not None and args.worker_id is None
     trace_path = None
-    if args.trace is not None and not coordinator:
-        # the coordinator process generates nothing — its workers each
-        # record their own namespaced trace (trace.w{k}.jsonl)
+    if args.trace is not None and not args.plan_only:
+        # a coordinator generates nothing — its workers each record
+        # their own namespaced trace (trace.w{k}.jsonl)
         trace_path = (os.path.join(args.out, "trace.jsonl")
                       if args.trace == "auto" else args.trace)
         if args.worker_id is not None:
@@ -300,9 +344,8 @@ def main(argv=None) -> int:
           f"pipeline_depth={job.pipeline_depth}, "
           f"host_workers={job.host_workers}, fused={job.fused}",
           file=sys.stderr)
-    if coordinator:
-        tracer.close()
-        return run_cluster(args, job)
+    if args.plan_only:
+        return plan_only(args, job)
     t0 = time.time()
     try:
         with jaxprof.trace(args.jax_profile):

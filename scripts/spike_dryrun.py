@@ -125,10 +125,7 @@ print(f"compile: {time.time()-t0:.2f}s")
 
 ma = compiled.memory_analysis()
 print("memory_analysis:", ma)
-import sys
-sys.path.insert(0, "src")
-from repro.utils import cost_analysis_compat
-ca = cost_analysis_compat(compiled)
+ca = compiled.cost_analysis()
 print("cost keys:", sorted(k for k in ca.keys())[:20] if hasattr(ca, 'keys') else type(ca))
 print("flops:", ca.get("flops") if hasattr(ca, "get") else None)
 print("bytes accessed:", ca.get("bytes accessed") if hasattr(ca, "get") else None)

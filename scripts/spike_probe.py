@@ -8,10 +8,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-import sys
-sys.path.insert(0, "src")
-from repro.utils import make_mesh_compat
-mesh = make_mesh_compat((4, 4), ("data", "model"))
+mesh = jax.make_mesh((4, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 D, H, KV, DFF, V = 256, 8, 4, 512, 1024
 HD = D // H
@@ -113,8 +111,7 @@ def lower_cell(L, scan):
             shapes(L), tok, tok)
         co = lo.compile()
     dt = time.time() - t0
-    from repro.utils import cost_analysis_compat
-    ca = cost_analysis_compat(co)
+    ca = co.cost_analysis()
     hlo = co.as_text()
     colls = Counter(re.findall(
         r"(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)\(", hlo))

@@ -1,6 +1,6 @@
 # The paper's primary contribution: the three-component synthetic graph
 # generation framework (structure / features / aligner) plus the chunked
-# trillion-edge generation machinery.
-from repro.core.pipeline import SyntheticGraphPipeline  # noqa: F401
-from repro.core.structure import KroneckerFit, fit_structure  # noqa: F401
-from repro.core import rmat  # noqa: F401
+# trillion-edge generation machinery.  Import the submodules directly
+# (``repro.core.pipeline``, ``repro.core.structure``, ...): this package
+# re-exports nothing, so ``repro.kernels`` can import ``repro.core.descend``
+# without loading the sampler, which imports the kernels.

@@ -27,7 +27,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.descend import (check_id_capacity, combine_ids_device,
                                 descend)
-from repro.utils import shard_map_compat as _shard_map
 
 
 def step_seeds(base_seed: int, step: int, n_dev: int) -> np.ndarray:
@@ -96,13 +95,13 @@ def device_generate(thetas, seeds, n: int, m: int, edges_per_device: int,
         return src_ids[None], dst_ids[None]
 
     if uniforms is not None:
-        fn = _shard_map(
+        fn = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(), P(axes), P(axes)),
             out_specs=(P(axes), P(axes)),
             check_vma=False)
         return fn(thetas, seeds, uniforms)
-    fn = _shard_map(
+    fn = jax.shard_map(
         lambda t, s: local(t, s, None), mesh=mesh,
         in_specs=(P(), P(axes)),
         out_specs=(P(axes), P(axes)),

@@ -24,11 +24,11 @@ backend                   what it is
                           edge output.  TPU-only (no interpret rule).
 ========================  ===========================================
 
-Selection (``resolve_backend(None)``): TPU → ``pallas_prng`` (falling
-back to ``pallas_bits`` if ``pltpu`` is missing) for device-resident
-speed, everything else → ``xla`` (interpret-mode Pallas is a correctness
-tool, not a fast path).  Tiny batches (< one kernel block) stay on
-``xla`` regardless — the pad-to-block waste would exceed the work.
+Selection (``resolve_backend(None)``): TPU → ``pallas_prng``, the one
+TPU sampler, everything else → ``xla`` (interpret-mode Pallas is a
+correctness tool, not a fast path).  Tiny batches (< one kernel block)
+stay on ``xla`` regardless — the pad-to-block waste would exceed the
+work.
 
 Id dtypes: ``int32`` ids cap at 31 bits; ``int64`` ids are produced via
 the ``(hi, lo)`` int32-pair descend (native int64 is unsupported on TPU
@@ -46,6 +46,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.descend import (LO_BITS, IdParts, check_id_capacity,
                                 combine_ids, descend, narrow_ids)
@@ -177,21 +178,17 @@ class PallasPrngBackend(EdgeSamplerBackend):
     name = "pallas_prng"
 
     def __init__(self, force_interpret: bool = False):
-        #: opt-in escape hatch for off-TPU smoke coverage: request pallas
-        #: interpret mode instead of refusing outright.  Lowering still
-        #: fails on hosts without interpret rules for ``pltpu.prng_*`` —
-        #: callers (the end-to-end test) map that to a skip with the
-        #: recorded reason.  Never the registered default.
+        #: opt-in plumbing coverage off-TPU: run the kernel under the TPU
+        #: interpreter, whose PRNG yields zero bits (every edge takes
+        #: quadrant a).  Never the registered default.
         self.force_interpret = bool(force_interpret)
 
     def available(self) -> bool:
         return self.why_unavailable() is None
 
     def why_unavailable(self) -> Optional[str]:
-        if rs.pltpu is None:
-            return "jax.experimental.pallas.tpu not importable"
         if jax.default_backend() != "tpu" and not self.force_interpret:
-            return ("pltpu.prng_* has no CPU/GPU interpret rule — "
+            return ("pltpu.prng_* generates zero bits off-TPU — "
                     "TPU-only backend")
         return None
 
@@ -201,19 +198,13 @@ class PallasPrngBackend(EdgeSamplerBackend):
             raise RuntimeError(f"backend 'pallas_prng' unavailable: "
                                f"{reason}; use 'pallas_bits' or 'xla'")
         block = choose_block(n_edges)
-        # seed with BOTH 32-bit key words (+ the block index in-kernel):
-        # a single 31-bit base seed with seed+pid block offsets would
-        # make distinct calls' block-seed intervals overlap and emit
-        # bit-identical blocks across chunks/shards
-        words = jax.random.key_data(key).reshape(-1)[-2:]
-        seed = jax.lax.bitcast_convert_type(words.astype(jnp.uint32),
-                                            jnp.int32)
-        return rs.rmat_sample_prng(seed,
+        n_pad = _pad_edges(n_edges, block)
+        interpret = (pltpu.InterpretParams()
+                     if jax.default_backend() != "tpu" else False)
+        return rs.rmat_sample_prng(rs.prng_block_seeds(key, n_pad // block),
                                    jnp.asarray(thetas, jnp.float32),
-                                   n, m, _pad_edges(n_edges, block),
-                                   block=block,
-                                   interpret=self.force_interpret
-                                   and jax.default_backend() != "tpu")
+                                   n, m, n_pad, block=block,
+                                   interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -257,10 +248,7 @@ def resolve_backend(name: Optional[str] = None,
     sub-block batches and non-TPU hosts get the jit reference path."""
     if name is not None and name != "auto":
         return get_backend(name)
-    if jax.default_backend() == "tpu":
-        if n_edges is not None and n_edges < MIN_BLOCK:
-            return _REGISTRY["xla"]
-        if _REGISTRY["pallas_prng"].available():
-            return _REGISTRY["pallas_prng"]
-        return _REGISTRY["pallas_bits"]
-    return _REGISTRY["xla"]
+    if jax.default_backend() != "tpu" or (n_edges is not None
+                                          and n_edges < MIN_BLOCK):
+        return _REGISTRY["xla"]
+    return _REGISTRY["pallas_prng"]

@@ -66,10 +66,9 @@ def compressed_psum(grads, error_buf, mesh, axis: str = "pod"):
         return deq, e_new
 
     specs = jax.tree.map(lambda _: P(), grads)
-    from repro.utils import shard_map_compat
-    fn = shard_map_compat(inner, mesh=mesh,
-                          in_specs=(specs, specs), out_specs=(specs, specs),
-                          check_vma=False)
+    fn = jax.shard_map(inner, mesh=mesh,
+                       in_specs=(specs, specs), out_specs=(specs, specs),
+                       check_vma=False)
     return fn(grads, error_buf)
 
 

@@ -22,7 +22,8 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import repro
 
-__all__ = ["WorkerProcess", "repro_pythonpath", "worker_log_name"]
+__all__ = ["WorkerProcess", "child_env", "repro_pythonpath",
+           "worker_log_name"]
 
 
 def repro_pythonpath() -> str:
@@ -34,6 +35,18 @@ def repro_pythonpath() -> str:
         return os.path.dirname(os.path.dirname(os.path.abspath(init)))
     # namespace package (no __init__.py): __path__ holds the package dir
     return os.path.dirname(os.path.abspath(list(repro.__path__)[0]))
+
+
+def child_env(extra: Optional[Dict[str, str]] = None) -> Dict[str, str]:
+    """This process's environment with ``repro_pythonpath()`` prepended to
+    ``PYTHONPATH`` (plus ``extra``), for children that run this
+    checkout's code."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [repro_pythonpath()] +
+        ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
+    env.update(extra or {})
+    return env
 
 
 def worker_log_name(worker_id: int) -> str:
@@ -58,13 +71,6 @@ class WorkerProcess:
         self.journal_path = journal_path
         self._offset = 0          # bytes of journal already consumed
         self._carry = b""         # partial line awaiting its newline
-        child_env = dict(os.environ)
-        child_env["PYTHONPATH"] = os.pathsep.join(
-            [repro_pythonpath()] +
-            ([child_env["PYTHONPATH"]] if child_env.get("PYTHONPATH")
-             else []))
-        if env:
-            child_env.update(env)
         self.log_path: Optional[str] = None
         self._log_file = None
         stdout = subprocess.DEVNULL
@@ -75,7 +81,7 @@ class WorkerProcess:
             stdout = self._log_file
         self.proc = subprocess.Popen(
             self.argv, stdout=stdout, stderr=subprocess.STDOUT,
-            env=child_env)
+            env=child_env(env))
 
     # -- lifecycle ---------------------------------------------------------
     def alive(self) -> bool:

@@ -16,8 +16,10 @@ where their uniforms come from:
   (validates the bit→uniform conversion used by the PRNG variant).
 * ``rmat_sample_prng``       — TPU-only: ``pltpu.prng_random_bits``
   generates bits in VMEM (§Perf optimized variant: HBM traffic drops ~L×
-  to the edge output).  ``pltpu.prng_*`` has no CPU interpret rule, so
-  this variant is compile-gated to TPU; its post-bits logic is exactly
+  to the edge output).  Each grid block seeds the core PRNG with its own
+  two-word seed row (Mosaic accepts at most two seed values).  The TPU
+  interpreter's ``prng_random_bits`` returns zeros, so off-TPU this
+  variant checks plumbing only; its post-bits logic is exactly
   ``rmat_sample_bits``'s.
 
 Node ids above 31 bits: TPUs have no native int64, so each wide id is
@@ -33,19 +35,21 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.descend import LO_BITS, IdParts, descend
 
-try:  # pltpu only needed for the PRNG variant
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
-
 DEFAULT_BLOCK = 8192
+#: most grid blocks one ``rmat_sample_prng`` call may seed: the seed rows
+#: sit whole in SMEM (1 MiB on v5e), 8 bytes per block
+MAX_PRNG_BLOCKS = 1 << 16
 
 
 def _bits_to_uniform(bits):
-    """uint32 -> U[0,1) float32 via mantissa trick (TPU-friendly, no div)."""
+    """uint32 -> U[0,1) float32 via mantissa trick (TPU-friendly, no div).
+    Signed words are refused: their right shift is arithmetic, which
+    turns every draw with the top bit set into NaN."""
+    assert bits.dtype == jnp.uint32, bits.dtype
     mant = jnp.right_shift(bits, jnp.uint32(9))
     one = jnp.uint32(0x3F800000)
     f = jax.lax.bitcast_convert_type(jnp.bitwise_or(mant, one), jnp.float32)
@@ -75,15 +79,18 @@ def _kernel_bits(theta_ref, bits_ref, *out_refs, n, m, block):
                  theta_ref, n, m, block, out_refs)
 
 
-def _kernel_prng(seed_ref, theta_ref, *out_refs, n, m, block):
-    """TPU-only: bits generated in VMEM.  The PRNG is seeded with both
-    32-bit key words plus the block index, so block streams are disjoint
-    across blocks AND across calls (a single 31-bit base + pid offset
-    would let different calls' seed intervals overlap)."""
+def _kernel_prng(seeds_ref, theta_ref, *out_refs, n, m, block):
+    """TPU-only: bits generated in VMEM.  Block ``i`` seeds the PRNG with
+    its own row ``seeds_ref[2i : 2i + 2]`` — two 32-bit words, the most
+    Mosaic accepts.  The rows are split keys of the call's key, so block
+    streams are disjoint across blocks and across calls; seeding with a
+    call-level word plus the block index would let the seed ranges of
+    different calls overlap."""
     pid = pl.program_id(0)
-    pltpu.prng_seed(seed_ref[0], seed_ref[1], pid)
+    pltpu.prng_seed(seeds_ref[2 * pid], seeds_ref[2 * pid + 1])
     L = max(n, m)
-    bits = pltpu.prng_random_bits((L, block))
+    # the TPU PRNG yields int32 words
+    bits = pltpu.bitcast(pltpu.prng_random_bits((L, block)), jnp.uint32)
     _run_descend(lambda ell: _bits_to_uniform(bits[ell, :]),
                  theta_ref, n, m, block, out_refs)
 
@@ -151,24 +158,35 @@ def rmat_sample_bits(thetas, bits, n: int, m: int,
     return pack(outs)
 
 
-def rmat_sample_prng(seed, thetas, n: int, m: int, n_edges: int,
-                     block: int = DEFAULT_BLOCK, interpret: bool = False
-                     ) -> Tuple[IdParts, IdParts]:
-    """TPU-only fast path (no HBM uniform traffic).  seed: (2,) int32
-    (the PRNG-key words; see ``_kernel_prng``).
+def prng_block_seeds(key, n_blocks: int):
+    """The ``(2 * n_blocks,)`` int32 seed words ``rmat_sample_prng``
+    takes: the key data of ``jax.random.split(key, n_blocks)``, one
+    two-word row per grid block."""
+    words = jax.random.key_data(jax.random.split(key, n_blocks))
+    return jax.lax.bitcast_convert_type(
+        words.reshape(n_blocks, -1)[:, -2:].astype(jnp.uint32),
+        jnp.int32).reshape(-1)
 
-    ``interpret=True`` requests pallas interpret mode: it only succeeds
-    where the host provides interpret rules for ``pltpu.prng_*`` — on
-    plain CPU jax it raises (no lowering for ``prng_seed``), which the
-    smoke test in ``tests/test_sampler.py`` maps to a skip."""
-    assert pltpu is not None, "requires TPU pallas"
+
+def rmat_sample_prng(seeds, thetas, n: int, m: int, n_edges: int,
+                     block: int = DEFAULT_BLOCK, interpret=False
+                     ) -> Tuple[IdParts, IdParts]:
+    """TPU-only fast path (no HBM uniform traffic).  seeds: the
+    ``(2 * n_edges // block,)`` int32 words of ``prng_block_seeds``.
+
+    ``interpret`` is passed to ``pallas_call``: ``pltpu.InterpretParams()``
+    runs the TPU interpreter, whose PRNG yields zero bits."""
     L = max(n, m)
     assert n_edges % block == 0
+    n_blocks = n_edges // block
+    assert seeds.shape == (2 * n_blocks,), (seeds.shape, n_blocks)
+    assert n_blocks <= MAX_PRNG_BLOCKS, \
+        f"{n_blocks} blocks > MAX_PRNG_BLOCKS={MAX_PRNG_BLOCKS}: split the call"
     kern = functools.partial(_kernel_prng, n=n, m=m, block=block)
     specs, shapes, pack = _out_layout(n, m, n_edges, block)
     outs = pl.pallas_call(
         kern,
-        grid=(n_edges // block,),
+        grid=(n_blocks,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((L, 4), lambda i: (0, 0)),
@@ -176,5 +194,5 @@ def rmat_sample_prng(seed, thetas, n: int, m: int, n_edges: int,
         out_specs=specs,
         out_shape=shapes,
         interpret=interpret,
-    )(seed, thetas)
+    )(seeds, thetas)
     return pack(outs)

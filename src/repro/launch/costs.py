@@ -129,8 +129,7 @@ def _lower_one(cfg, shape, mesh, hp=None) -> Dict[str, Any]:
         lowered = jax.jit(cell.fn, in_shardings=cell.in_shardings,
                           out_shardings=cell.out_shardings).lower(*cell.args)
         compiled = lowered.compile()
-    from repro.utils import cost_analysis_compat
-    ca = cost_analysis_compat(compiled)
+    ca = compiled.cost_analysis()
     hlo = compiled.as_text()
     colls = parse_collectives(hlo, n_devices_per_group=mesh.shape.get("model", 2))
     return {

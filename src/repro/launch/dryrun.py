@@ -90,8 +90,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: str,
             compiled = lowered.compile()
             t_compile = time.time() - t0
         ma = compiled.memory_analysis()
-        from repro.utils import cost_analysis_compat
-        ca = cost_analysis_compat(compiled)
+        ca = compiled.cost_analysis()
         print(compiled.memory_analysis())
         rec["status"] = "ok"
         rec["t_lower_s"] = round(t_lower, 2)
@@ -173,8 +172,7 @@ def run_graphgen_cell(mesh_kind: str, out_dir: str, scale: str = "1t",
                               out_shardings=cell.out_shardings).lower(*cell.args)
             compiled = lowered.compile()
         ma = compiled.memory_analysis()
-        from repro.utils import cost_analysis_compat
-        ca = cost_analysis_compat(compiled)
+        ca = compiled.cost_analysis()
         print(compiled.memory_analysis())
         colls = costs_mod.parse_collectives(compiled.as_text(),
                                             mesh.shape.get("model", 2))

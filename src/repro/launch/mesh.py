@@ -9,7 +9,10 @@ from __future__ import annotations
 
 import jax
 
-from repro.utils import make_mesh_compat as _make_mesh
+
+def _make_mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

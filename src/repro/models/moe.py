@@ -218,9 +218,8 @@ def moe_ffn_ep(w, x, cfg, mesh):
                 P("model", None, None), P("model", None, None),
                 P("model", None, None))
     out_specs = P(batch_ax if batch_ax else None, None, None)
-    from repro.utils import shard_map_compat
-    fn = shard_map_compat(local_moe, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(local_moe, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     out = fn(x, w["gate"], w["w1"], w["w3"], w["w2"])
     # aux loss computed (cheaply, replicated) outside the shard_map
     _, _, aux = _route(x.reshape(1, B * S, D), w["gate"], cfg)

@@ -13,9 +13,10 @@ additionally:
   bit-pair blocks — with ``TraceAnnotation`` named ranges so device
   work correlates back to pipeline stages by name.
 
-Everything degrades to a no-op when profiling is off (the common case):
+Everything is a no-op when profiling is off (the common case):
 ``annotation()`` returns a shared null context, so instrumented code
-pays one call and one truthiness check.
+pays one call and one truthiness check.  When profiling is on, a
+profiler error propagates instead of being swallowed.
 """
 from __future__ import annotations
 
@@ -51,28 +52,20 @@ def annotation(name: str):
     active, else a shared no-op context."""
     if _active_dir is None:
         return _NULL
-    try:
-        import jax
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:       # noqa: BLE001 — profiling must never break a run
-        return _NULL
+    import jax
+    return jax.profiler.TraceAnnotation(name)
 
 
 def start(log_dir: str) -> bool:
-    """Begin a device trace into ``log_dir``.  Returns False (and stays
-    inert) when the jax profiler is unavailable on this host."""
+    """Begin a device trace into ``log_dir``.  A profiler that fails to
+    start raises: a run asked for a device trace must not finish
+    without one."""
     global _active_dir
     with _lock:
         if _active_dir is not None:
             return True
-        try:
-            import jax
-            jax.profiler.start_trace(log_dir)
-        except Exception as e:     # noqa: BLE001
-            import sys
-            print(f"warning: jax profiler unavailable ({e!r}) — "
-                  f"continuing without device trace", file=sys.stderr)
-            return False
+        import jax
+        jax.profiler.start_trace(log_dir)
         _active_dir = log_dir
         return True
 
@@ -84,11 +77,8 @@ def stop() -> Optional[str]:
         if _active_dir is None:
             return None
         log_dir, _active_dir = _active_dir, None
-        try:
-            import jax
-            jax.profiler.stop_trace()
-        except Exception:          # noqa: BLE001
-            pass
+        import jax
+        jax.profiler.stop_trace()
         return log_dir
 
 

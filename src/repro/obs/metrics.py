@@ -219,25 +219,24 @@ def _git_sha() -> Optional[str]:
 
 def run_env() -> Dict[str, Any]:
     """Host/device provenance stamped on every benchmark/metrics file —
-    numbers without the machine that produced them don't trend."""
+    numbers without the machine that produced them don't trend.  The
+    device is named as JAX reports it: ``platform``, ``device_kind``
+    and ``device_count`` of ``jax.devices()``."""
     import platform
-    env: Dict[str, Any] = {
+
+    import jax
+    devices = jax.devices()
+    return {
         "git_sha": _git_sha(),
         "host": platform.node(),
-        "platform": platform.platform(),
+        "os": platform.platform(),
         "python": sys.version.split()[0],
         "cpu_count": os.cpu_count(),
+        "jax": jax.__version__,
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
     }
-    try:
-        import jax
-        env["jax"] = jax.__version__
-        env["device"] = jax.default_backend()
-        env["n_devices"] = jax.device_count()
-    except Exception:        # noqa: BLE001 — env report must never fail
-        env["jax"] = None
-        env["device"] = None
-        env["n_devices"] = None
-    return env
 
 
 def bench_envelope(suite: str, metrics: Any,

@@ -2,9 +2,17 @@
 from __future__ import annotations
 
 import inspect
+import os
 
 import jax
 import jax.numpy as jnp
+
+#: JAX's persistent compilation cache for runs from this checkout, where
+#: ``JAX_COMPILATION_CACHE_DIR`` does not name one.  A fixed path: the
+#: path is part of the cache key, so a directory that moves never hits.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__)))), ".jax_cache")
 
 
 def parse_count(s: str) -> int:
@@ -16,6 +24,18 @@ def parse_count(s: str) -> int:
         a, b = s.split("<<")
         return int(a) << int(b)
     return int(float(s))
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory: ``JAX_COMPILATION_CACHE_DIR`` when set (JAX reads it
+    itself, so nothing is set here), else ``COMPILE_CACHE_DIR``.  Called
+    by the entry-point scripts, never on import."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
 
 def accepts_kwarg(fn, name: str) -> bool:
@@ -40,36 +60,6 @@ def call_with_optional_kwargs(fn, *args, **optional):
     kwargs = {k: v for k, v in optional.items()
               if v is not None and accepts_kwarg(fn, k)}
     return fn(*args, **kwargs)
-
-
-def shard_map_compat(fn, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` landed in 0.6; fall back to the experimental API
-    (where ``check_vma`` is spelled ``check_rep``) on older runtimes."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
-def make_mesh_compat(shape, axes):
-    """``jax.sharding.AxisType`` landed in 0.5.x; older runtimes default
-    every axis to Auto, so omitting the kwarg there is equivalent."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,)
-                             * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def cost_analysis_compat(compiled) -> dict:
-    """``Compiled.cost_analysis()`` returned a one-element list of dicts on
-    jax 0.4.x and a flat dict from 0.5 on; normalize to the dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
 
 
 def tree_size(tree) -> int:

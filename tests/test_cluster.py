@@ -16,7 +16,8 @@ from repro.datastream import (DatasetJob, Manifest, ShardedGraphDataset,
                               worker_journal_name, worker_journal_paths)
 from repro.datastream.writer import JOURNAL_NAME, MANIFEST_NAME
 from repro.distributed.cluster import ClusterCoordinator, ClusterError
-from repro.distributed.launcher import WorkerProcess, repro_pythonpath
+from repro.distributed.launcher import (WorkerProcess, child_env,
+                                       repro_pythonpath)
 
 FIT = KroneckerFit(a=0.45, b=0.22, c=0.2, d=0.13, n=10, m=10, E=8_000)
 SHARD_EDGES = 2_000
@@ -239,6 +240,62 @@ def test_cli_worker_stripe_mode(serial_ref, tmp_path):
     merged = Manifest.load(out)
     merged.merge_worker_journals(out)
     assert merged.is_complete()
+    assert _file_hashes(out) == _file_hashes(ref_out)
+
+
+def test_cli_refuses_more_workers_than_chips(tmp_path, monkeypatch):
+    """A chip belongs to one process at a time: on a TPU host the
+    planning step refuses more worker processes than chips before a
+    manifest lands on disk.  The TPU host is simulated by steering
+    ``jax.default_backend`` / ``jax.local_device_count``."""
+    import jax
+    gen_cli = _load_script("generate_dataset")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "local_device_count", lambda: 1)
+    out = str(tmp_path / "ds")
+    with pytest.raises(SystemExit, match="exceeds the 1 TPU chip"):
+        gen_cli.main(["--fit", "demo", "--edges", "8000", "--shard-edges",
+                      str(SHARD_EDGES), "--out", out, "--plan-only",
+                      "--num-workers", "2"])
+    assert not Manifest.exists(out)
+
+
+def test_coordinator_process_initializes_no_jax_backend(serial_ref,
+                                                        tmp_path):
+    """The ``--num-workers`` coordinator leaves JAX uninitialized for its
+    whole run — the planner and the workers are child processes, and on
+    a TPU host a parent that holds the chip would lock them out.  Output
+    stays byte-identical to the single-process reference."""
+    import subprocess
+    import textwrap
+    ref_out, _ = serial_ref
+    fit_json = str(tmp_path / "fit.json")
+    with open(fit_json, "w") as f:
+        json.dump(dataclasses.asdict(FIT), f)
+    out = str(tmp_path / "ds")
+    argv = [SCRIPT, "--fit", fit_json, "--shard-edges", str(SHARD_EDGES),
+            "--out", out, "--seed", str(SEED), "--serial",
+            "--num-workers", "2", "--verify"]
+    body = textwrap.dedent(f"""
+        import runpy, sys
+        from jax._src import xla_bridge
+        from repro.distributed import cluster
+        run = cluster.ClusterCoordinator.run
+        def checked(self):
+            assert not xla_bridge.backends_are_initialized(), "at spawn"
+            return run(self)
+        cluster.ClusterCoordinator.run = checked
+        sys.argv = {argv!r}
+        try:
+            runpy.run_path(sys.argv[0], run_name="__main__")
+        except SystemExit as e:
+            assert e.code in (0, None), e.code
+        assert not xla_bridge.backends_are_initialized(), "after the run"
+        print("no backend")
+    """)
+    r = subprocess.run([sys.executable, "-c", body], capture_output=True,
+                       text=True, env=child_env())
+    assert r.returncode == 0 and "no backend" in r.stdout, r.stderr[-3000:]
     assert _file_hashes(out) == _file_hashes(ref_out)
 
 

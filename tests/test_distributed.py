@@ -41,8 +41,8 @@ def test_resolve_spec_divisibility_fallback():
     import jax
     from jax.sharding import PartitionSpec as P
     from repro.distributed.sharding import resolve_spec
-    from repro.utils import make_mesh_compat
-    mesh = make_mesh_compat((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     rules = {"vocab": ("model",), "heads": ("model",), "batch": (("data",),)}
     # divisible -> sharded
     assert resolve_spec(("vocab", None), (64, 7), rules, mesh) == P("model")
@@ -72,8 +72,8 @@ def test_zero_opt_sharding_adds_data_axis():
     from repro.distributed.sharding import make_rules
     from repro.training.steps import opt_state_shardings
     from repro.training.optimizer import abstract_opt_state
-    from repro.utils import make_mesh_compat
-    mesh = make_mesh_compat((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     cfg = get_config("tinyllama-1.1b").smoke()
     m = Model(cfg)
     o = abstract_opt_state(m.abstract_params())
@@ -138,12 +138,13 @@ def test_elastic_restore_different_mesh(tmp_path):
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P, NamedSharding
     from repro.distributed import checkpoint as ck
-    from repro.utils import make_mesh_compat
-    mesh1 = make_mesh_compat((2, 2), ("data", "model"))
+    mesh1 = jax.make_mesh((2, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     x = jnp.arange(64, dtype=jnp.float32).reshape(8, 8)
     xs = jax.device_put(x, NamedSharding(mesh1, P("data", "model")))
     ck.save({str(tmp_path)!r}, 1, {{"w": xs}})
-    mesh2 = make_mesh_compat((4, 1), ("data", "model"))
+    mesh2 = jax.make_mesh((4, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     sh2 = {{"w": NamedSharding(mesh2, P("model", "data"))}}
     restored, _ = ck.restore({str(tmp_path)!r}, {{"w": x}}, shardings=sh2)
     np.testing.assert_array_equal(np.asarray(restored["w"]), np.asarray(x))
@@ -174,8 +175,8 @@ def test_compressed_psum_multidevice():
     _run_subprocess("""
     import jax, jax.numpy as jnp, numpy as np
     from repro.distributed.compression import compressed_psum, init_error_buffer
-    from repro.utils import make_mesh_compat
-    mesh = make_mesh_compat((4,), ("pod",))
+    mesh = jax.make_mesh((4,), ("pod",),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 1)
     g = {"w": jnp.ones((8, 8), jnp.float32) * 2.0}
     e = init_error_buffer(g)
     with mesh:

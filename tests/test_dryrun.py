@@ -93,8 +93,7 @@ def test_cell_lowers_on_small_mesh(arch):
             c = jax.jit(cell.fn, in_shardings=cell.in_shardings,
                         out_shardings=cell.out_shardings).lower(
                 *cell.args).compile()
-        from repro.utils import cost_analysis_compat
-        assert cost_analysis_compat(c)["flops"] > 0
+        assert c.cost_analysis()["flops"] > 0
         print(shape.kind, "ok")
     """)
 

@@ -10,6 +10,7 @@ import os
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -209,9 +210,27 @@ def test_bench_envelope_schema():
     assert env["schema_version"] == SCHEMA_VERSION
     assert env["suite"] == "unit" and env["metrics"] == {"x": 1}
     assert env["note"] == "t"
-    for key in ("git_sha", "host", "python", "cpu_count", "jax", "device"):
+    for key in ("git_sha", "host", "python", "cpu_count", "jax", "platform",
+                "device_kind", "device_count"):
         assert key in env["env"]
+    assert env["env"]["platform"] == jax.devices()[0].platform
+    assert env["env"]["device_count"] == len(jax.devices())
     json.dumps(env)                           # serializable as-is
+
+
+def test_jax_profile_start_failure_raises(monkeypatch, tmp_path):
+    """A run that asked for a device trace fails when the profiler cannot
+    start, instead of finishing without one."""
+    from repro.obs import jaxprof
+
+    def broken(log_dir):
+        raise RuntimeError("profiler unavailable")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", broken)
+    with pytest.raises(RuntimeError, match="profiler unavailable"):
+        with jaxprof.trace(str(tmp_path)):
+            pass
+    assert not jaxprof.profiling()
 
 
 # -- chrome trace export -----------------------------------------------------
