@@ -16,7 +16,10 @@ reports:
   pipeline actually hid host or IO time behind the device), and
 * queue-stall attribution: how long the commit path sat blocked waiting
   on the host feature stage (``stall.host``) vs on a write-queue slot
-  (``stall.write``) — i.e. *which* stage to widen next.
+  (``stall.write``) — i.e. *which* stage to widen next, and
+* a ``compile`` row: JAX's compile phases (``compile.trace``/``.lower``/
+  ``.backend`` spans, nested inside whichever stage compiled), which are
+  time inside a stage, never a busy stage of their own.
 
 ``--perfetto OUT`` additionally converts the log to Chrome trace-event
 JSON (load in https://ui.perfetto.dev or chrome://tracing) where the
@@ -66,6 +69,8 @@ def summarize(events: List[Dict[str, Any]]) -> Dict[str, Any]:
         return stages.get(prefix, {}).get("busy_s", 0.0)
 
     busy_s = sum(total(k) for k in BUSY_STAGES)
+    compile_s = {k[len("compile."):]: v["busy_s"]
+                 for k, v in stages.items() if k.startswith("compile.")}
     stall_host = total("stall.host")
     stall_write = total("stall.write")
     stall_s = stall_host + stall_write
@@ -77,6 +82,7 @@ def summarize(events: List[Dict[str, Any]]) -> Dict[str, Any]:
         "overlap": (busy_s / wall_s if wall_s > 0 else 0.0),
         "stages": {k: stages[k] for k in sorted(stages)},
         "stage_s": {k: total(k) for k in BUSY_STAGES},
+        "compile": {"total_s": sum(compile_s.values()), **compile_s},
         "stall": {
             "total_s": stall_s,
             "host_s": stall_host,
@@ -94,6 +100,11 @@ def format_report(rep: Dict[str, Any]) -> str:
     for name, st in rep["stages"].items():
         lines.append(f"{name:<24}{st['busy_s']:>10.3f}{st['count']:>8}"
                      f"{st['mean_s'] * 1e3:>10.2f}")
+    comp = rep["compile"]
+    if comp["total_s"] > 0:
+        lines.append(f"{'compile':<24}{comp['total_s']:>10.3f}  ("
+                     + ", ".join(f"{k} {v:.3f}" for k, v in comp.items()
+                                 if k != "total_s") + ")")
     stall = rep["stall"]
     lines.append("")
     if stall["total_s"] >= 0.01:

@@ -515,7 +515,6 @@ def accumulate(source, sample_rows: int = 100_000, seed: int = 0,
     accumulator.  Memory: one chunk + the sketches.  ``tracer`` (a
     ``repro.obs`` tracer) records per-chunk ``fit.read``/``fit.update``
     spans and a ``fit.finalize`` span."""
-    from repro.obs import jaxprof
     from repro.obs.trace import NULL_TRACER
     tracer = tracer if tracer is not None else NULL_TRACER
 
@@ -539,8 +538,7 @@ def accumulate(source, sample_rows: int = 100_000, seed: int = 0,
         n_chunks += 1
         with tracer.span("fit.update", chunk=n_chunks - 1,
                          rows=chunk.n_rows):
-            with jaxprof.annotation("fit.update"):
-                mle.update(chunk.src, chunk.dst)
+            mle.update(chunk.src, chunk.dst)
             sk_out.update(chunk.src)
             sk_in.update(chunk.dst)
             res.update(chunk)

@@ -40,7 +40,9 @@ threads them through the source, the feature spec and the writer, so
 every stage reports into one timeline: ``struct`` spans on the calling
 thread, ``feat``/``align`` spans on the host pool threads, ``write``
 spans on the flush thread, ``stall.host``/``stall.write`` spans where
-the pipeline blocked.  ``ExecutorStats`` is *derived from* those spans
+the pipeline blocked, and JAX's compiles as ``compile.*`` spans under
+whichever span was open where they ran (``Tracer.watch_jax``, open for
+the whole run).  ``ExecutorStats`` is *derived from* those spans
 (same keys and semantics as the ad-hoc timers it replaced); attach a
 sink (``--trace``) and the identical numbers come with a replayable
 event log.
@@ -273,8 +275,9 @@ class ShardExecutor:
         t0 = {k: self.tracer.total(k) for k in self._STAGE_TOTALS}
         t_wall = time.perf_counter()
         try:
-            with self.tracer.span("run", n_shards=len(records),
-                                  depth=self.pipeline_depth):
+            with self.tracer.watch_jax(), \
+                    self.tracer.span("run", n_shards=len(records),
+                                     depth=self.pipeline_depth):
                 if self.pipeline_depth == 0:
                     self._run_serial(records, stats)
                 else:

@@ -48,7 +48,6 @@ from repro.core.structure import KroneckerFit
 from repro.datastream.scheduler import ChunkScheduler
 from repro.datastream.writer import ShardRecord, pump_chunks
 from repro.graph.ops import compact_subgraph
-from repro.obs import jaxprof
 from repro.obs.trace import NULL_TRACER
 from repro.utils import call_with_optional_kwargs
 
@@ -189,11 +188,27 @@ class ShardSource:
 
     name = "base"
     #: replaced per-instance by the executor's ``_adopt_obs`` so struct
-    #: sub-spans (dispatch/combine/device_step) land in the run timeline
+    #: sub-spans (dispatch/fetch/combine/device_step) land in the run
+    #: timeline and the ``struct.*`` counters in the run's registry
     tracer = NULL_TRACER
+    metrics = None
 
     def generate(self, rec: ShardRecord) -> Dict[str, np.ndarray]:
         raise NotImplementedError
+
+    def _count(self, name: str, unit: str, n: float) -> None:
+        if self.metrics is not None:
+            self.metrics.counter(name, unit).inc(n)
+
+    def _fetch(self, tree, **where):
+        """``jax.device_get(tree)`` under a ``struct.fetch`` span that
+        names the chunk or shard (``where``) and the bytes copied, which
+        ``struct.bytes_fetched`` counts."""
+        nbytes = sum(x.nbytes for x in jax.tree.leaves(tree))
+        with self.tracer.span("struct.fetch", bytes=nbytes, **where):
+            host = jax.device_get(tree)
+        self._count("struct.bytes_fetched", "bytes", nbytes)
+        return host
 
 
 class ChunkShardSource(ShardSource):
@@ -317,23 +332,23 @@ class ChunkShardSource(ShardSource):
             fkey = params = None
         with self.tracer.span("struct.fused", shard=rec.shard_id,
                               chunks=len(chunks), feature_blocks=n_blocks):
-            with jaxprof.annotation("struct.fused"):
-                edges, feats = jax.device_get(
-                    fn(keys, spre, dpre, params, fkey))
-                if wide:
-                    src_buf = np.empty(rec.n_edges, dt)
-                    dst_buf = np.empty(rec.n_edges, dt)
-                    off = 0
-                    for ck, (sp, dp) in zip(chunks, edges):
-                        src_buf[off: off + ck.n_edges] = combine_ids(
-                            sp, n_s, dt, prefix=ck.src_prefix)[: ck.n_edges]
-                        dst_buf[off: off + ck.n_edges] = combine_ids(
-                            dp, m_s, dt, prefix=ck.dst_prefix)[: ck.n_edges]
-                        off += ck.n_edges
-                    arrays = {"src": src_buf, "dst": dst_buf}
-                else:
-                    arrays = {"src": np.asarray(edges[0]),
-                              "dst": np.asarray(edges[1])}
+            out = fn(keys, spre, dpre, params, fkey)
+            self._count("struct.chunks", "chunks", len(chunks))
+            edges, feats = self._fetch(out, shard=rec.shard_id)
+            if wide:
+                src_buf = np.empty(rec.n_edges, dt)
+                dst_buf = np.empty(rec.n_edges, dt)
+                off = 0
+                for ck, (sp, dp) in zip(chunks, edges):
+                    src_buf[off: off + ck.n_edges] = combine_ids(
+                        sp, n_s, dt, prefix=ck.src_prefix)[: ck.n_edges]
+                    dst_buf[off: off + ck.n_edges] = combine_ids(
+                        dp, m_s, dt, prefix=ck.dst_prefix)[: ck.n_edges]
+                    off += ck.n_edges
+                arrays = {"src": src_buf, "dst": dst_buf}
+            else:
+                arrays = {"src": np.asarray(edges[0]),
+                          "dst": np.asarray(edges[1])}
         if feats is not None:
             arrays["cont"] = np.asarray(feats[0])[: rec.n_edges]
             arrays["cat"] = np.asarray(feats[1])[: rec.n_edges]
@@ -363,17 +378,16 @@ class ChunkShardSource(ShardSource):
 
         def dispatch(ck):
             # host span times dispatch only (the device call is async);
-            # the jaxprof annotation names the device-side range when a
-            # --jax-profile trace is active
+            # the copy back is the struct.fetch span
+            self._count("struct.chunks", "chunks", 1)
             with self.tracer.span("struct.dispatch", chunk=ck.index):
-                with jaxprof.annotation("struct.dispatch"):
-                    if wide:
-                        return be.sample_parts(sched.key_for(ck), suffix,
-                                               n_s, m_s, ck.n_edges)
-                    return rmat.sample_chunk(sched.key_for(ck), self.fit,
-                                             ck, sched.k_pref,
-                                             sched.thetas, dtype=np_dtype,
-                                             backend=self.backend)
+                if wide:
+                    return be.sample_parts(sched.key_for(ck), suffix,
+                                           n_s, m_s, ck.n_edges)
+                return rmat.sample_chunk(sched.key_for(ck), self.fit,
+                                         ck, sched.k_pref,
+                                         sched.thetas, dtype=np_dtype,
+                                         backend=self.backend)
 
         def flush(ck, host):
             off = offsets[ck.index]
@@ -390,7 +404,8 @@ class ChunkShardSource(ShardSource):
                 dst_buf[off: off + ck.n_edges] = d
 
         pump_chunks(chunks, dispatch, flush,
-                    double_buffered=self.double_buffered)
+                    double_buffered=self.double_buffered,
+                    fetch=lambda ck, bufs: self._fetch(bufs, chunk=ck.index))
         return {"src": src_buf, "dst": dst_buf}
 
 
@@ -420,10 +435,10 @@ class DeviceStepShardSource(ShardSource):
     def _setup(self):
         """Build the mesh + jitted step function once per source: every
         step shares shapes, so the shard_map trace/compile is paid a
-        single time and steps differ only in their seed vector."""
+        single time (inside the first step's span) and steps differ only
+        in their seed vector."""
         if self._step is None:
-            with self.tracer.span("struct.compile"):
-                self._step = self._build_step()
+            self._step = self._build_step()
         return self._step
 
     def _build_step(self):
@@ -495,22 +510,19 @@ class DeviceStepShardSource(ShardSource):
             if self.fused else (None, 0, 0)
         span = "struct.fused" if n_blocks else "struct.device_step"
         with self.tracer.span(span, shard=rec.shard_id):
-            with jaxprof.annotation(span):
-                seeds = jnp.asarray(step_seeds(self.seed, rec.shard_id,
-                                               n_dev))
-                if n_blocks:
-                    fkey = jax.random.PRNGKey(
-                        self.features.feature_key_int(self.seed,
-                                                      rec.shard_id))
-                    params = self.features.generator.params["g"]
-                    fn = self._fused_step(n_blocks, b)
-                    (src, dst), (cont, cat) = jax.device_get(
-                        fn(seeds, params, fkey))
-                    return {"src": np.asarray(src)[: rec.n_edges],
-                            "dst": np.asarray(dst)[: rec.n_edges],
-                            "cont": np.asarray(cont)[: rec.n_edges],
-                            "cat": np.asarray(cat)[: rec.n_edges]}
-                src, dst = step(seeds)
-                src = np.asarray(jax.device_get(src)).reshape(-1)
-                dst = np.asarray(jax.device_get(dst)).reshape(-1)
+            seeds = jnp.asarray(step_seeds(self.seed, rec.shard_id, n_dev))
+            if n_blocks:
+                fkey = jax.random.PRNGKey(
+                    self.features.feature_key_int(self.seed, rec.shard_id))
+                params = self.features.generator.params["g"]
+                fn = self._fused_step(n_blocks, b)
+                (src, dst), (cont, cat) = self._fetch(
+                    fn(seeds, params, fkey), shard=rec.shard_id)
+                return {"src": np.asarray(src)[: rec.n_edges],
+                        "dst": np.asarray(dst)[: rec.n_edges],
+                        "cont": np.asarray(cont)[: rec.n_edges],
+                        "cat": np.asarray(cat)[: rec.n_edges]}
+            src, dst = self._fetch(step(seeds), shard=rec.shard_id)
+            src = np.asarray(src).reshape(-1)
+            dst = np.asarray(dst).reshape(-1)
         return {"src": src[: rec.n_edges], "dst": dst[: rec.n_edges]}

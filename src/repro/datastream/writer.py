@@ -530,31 +530,38 @@ class AsyncFlushQueue:
                 f"shard flush failed: {err!r}") from err
 
 
+def _device_get(item, bufs):
+    return jax.device_get(bufs)
+
+
 def pump_chunks(work: Iterable, dispatch: Callable, flush: Callable,
-                double_buffered: bool = True) -> int:
+                double_buffered: bool = True,
+                fetch: Callable = _device_get) -> int:
     """Double-buffered device→host pump.
 
     ``dispatch(item)`` launches device generation for one chunk and returns
-    the (not yet materialized) device buffers; ``flush(item, host_arrays)``
-    consumes the ``jax.device_get`` of those buffers.  With double
-    buffering, chunk *i+1* is dispatched *before* chunk *i* is fetched, so
-    the device computes while the host copies/writes (JAX dispatch is
-    async).  ``double_buffered=False`` is the serial baseline: fetch and
-    flush each chunk before dispatching the next.  Returns #items pumped.
+    the (not yet materialized) device buffers; ``fetch(item, bufs)`` copies
+    them to the host (``jax.device_get`` unless the caller times or counts
+    the copy); ``flush(item, host_arrays)`` consumes the host copy.  With
+    double buffering, chunk *i+1* is dispatched *before* chunk *i* is
+    fetched, so the device computes while the host copies/writes (JAX
+    dispatch is async).  ``double_buffered=False`` is the serial baseline:
+    fetch and flush each chunk before dispatching the next.  Returns #items
+    pumped.
     """
     n = 0
     prev = None
     for item in work:
         bufs = dispatch(item)
         if not double_buffered:
-            flush(item, jax.device_get(bufs))
+            flush(item, fetch(item, bufs))
             n += 1
             continue
         if prev is not None:
-            flush(prev[0], jax.device_get(prev[1]))
+            flush(prev[0], fetch(*prev))
             n += 1
         prev = (item, bufs)
     if prev is not None:
-        flush(prev[0], jax.device_get(prev[1]))
+        flush(prev[0], fetch(*prev))
         n += 1
     return n

@@ -132,6 +132,7 @@ def rmat_sample_uniforms(thetas, uniforms, n: int, m: int,
         out_specs=specs,
         out_shape=shapes,
         interpret=interpret,
+        name="rmat_sample_uniforms",
     )(thetas, uniforms)
     return pack(outs)
 
@@ -154,6 +155,7 @@ def rmat_sample_bits(thetas, bits, n: int, m: int,
         out_specs=specs,
         out_shape=shapes,
         interpret=interpret,
+        name="rmat_sample_bits",
     )(thetas, bits)
     return pack(outs)
 
@@ -194,5 +196,6 @@ def rmat_sample_prng(seeds, thetas, n: int, m: int, n_edges: int,
         out_specs=specs,
         out_shape=shapes,
         interpret=interpret,
+        name="rmat_sample_prng",
     )(seeds, thetas)
     return pack(outs)

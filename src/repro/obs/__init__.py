@@ -10,6 +10,13 @@ observability layer every hot path reports through:
   pipeline stage timings (``gen_struct_s``/``gen_feat_s``/
   ``gen_align_s``/``gen_write_s``/``gen_overlap``) are *derived from*
   these spans — the ad-hoc lock-guarded floats they replaced are gone.
+  ``tracer.watch_jax()`` (open for a whole ``ShardExecutor.run``) books
+  JAX's compile events as ``compile.trace``/``compile.lower``/
+  ``compile.backend`` spans (``fun`` = JAX's function name) under the
+  span open where they ran.  The struct stage adds ``struct.fetch``
+  spans (each device-to-host copy, with ``chunk``/``shard`` and
+  ``bytes``) and the ``struct.chunks``/``struct.bytes_fetched``
+  counters.
 * ``metrics`` — counter/gauge/histogram registry (rows written, bytes
   flushed, queue depth, backpressure stalls, shard commit latency with
   p50/p95/p99) plus the unified ``BENCH_*.json`` envelope
@@ -18,8 +25,10 @@ observability layer every hot path reports through:
   (written next to the dataset manifest by ``--trace``).
 * ``export``  — Chrome-trace/Perfetto conversion of an event log, so a
   pipelined run renders as a Gantt of struct/feature/write overlap.
-* ``jaxprof`` — optional ``jax.profiler`` bracketing of jit boundaries
-  for device-side attribution (``--jax-profile``).
+* ``jaxprof`` — optional ``jax.profiler`` trace of a run
+  (``--jax-profile``); while it records, every tracer span is also a
+  ``TraceAnnotation`` of its name, so the spans sit on the device
+  trace's clock.
 
 ``scripts/report_run.py`` turns an event log into a per-stage
 breakdown, overlap factor and queue-stall attribution.

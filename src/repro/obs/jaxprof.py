@@ -8,15 +8,14 @@ additionally:
 
 * starts a ``jax.profiler`` trace into ``DIR`` (open it in TensorBoard
   or Perfetto for the device timeline), and
-* brackets the jit boundaries of the hot path —
-  ``DeviceStepShardSource`` steps, chunk dispatches, the fit engine's
-  bit-pair blocks — with ``TraceAnnotation`` named ranges so device
-  work correlates back to pipeline stages by name.
+* while it runs, every ``repro.obs`` tracer span opens a
+  ``TraceAnnotation`` of its own name on its own thread
+  (``repro.obs.trace``), so device work correlates back to pipeline
+  stages by name, on the trace's clock.
 
-Everything is a no-op when profiling is off (the common case):
-``annotation()`` returns a shared null context, so instrumented code
-pays one call and one truthiness check.  When profiling is on, a
-profiler error propagates instead of being swallowed.
+When profiling is off (the common case) a span pays one call to
+``profiling()``.  When profiling is on, a profiler error propagates
+instead of being swallowed.
 """
 from __future__ import annotations
 
@@ -24,36 +23,14 @@ import contextlib
 import threading
 from typing import Optional
 
-__all__ = ["annotation", "start", "stop", "profiling"]
+__all__ = ["start", "stop", "profiling", "trace"]
 
 _lock = threading.Lock()
 _active_dir: Optional[str] = None
 
 
-class _NullCtx:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return None
-
-
-_NULL = _NullCtx()
-
-
 def profiling() -> bool:
     return _active_dir is not None
-
-
-def annotation(name: str):
-    """A ``jax.profiler.TraceAnnotation(name)`` while a profile is
-    active, else a shared no-op context."""
-    if _active_dir is None:
-        return _NULL
-    import jax
-    return jax.profiler.TraceAnnotation(name)
 
 
 def start(log_dir: str) -> bool:

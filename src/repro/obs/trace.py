@@ -22,6 +22,21 @@ host feature spans (``shard-feat`` pool threads) and writer flush spans
 (``shard-flush`` thread) nest independently and carry their own ``tid``
 — exactly the three lanes a Chrome-trace Gantt shows overlapping.
 
+Compiles: inside ``with tracer.watch_jax():`` JAX's own compile-pipeline
+events become closed spans — ``compile.trace`` (jaxpr tracing),
+``compile.lower`` (jaxpr → MLIR) and ``compile.backend`` (XLA compile or
+a persistent-cache load) — each with ``fun`` = JAX's name for the
+function, under the innermost span open on the compiling thread.  JAX
+reports a phase when it ends, so nested phases (the per-primitive traces
+inside a kernel's trace, the re-traces a lowering makes) arrive before
+the phase that holds them; a phase absorbs the ones it holds, so a
+thread's compile spans never overlap and their totals add up to the
+union of its compile time.
+
+Device traces: while ``repro.obs.jaxprof`` has a profile running, every
+span also opens a ``jax.profiler.TraceAnnotation`` of its name on its
+own thread, so the device trace carries the spans on its own clock.
+
 Overhead: a sink-less tracer costs two ``perf_counter`` calls plus one
 locked dict update per span — the same price as the legacy ad-hoc
 timers it replaces.  The module-level :data:`NULL_TRACER` is cheaper
@@ -32,13 +47,23 @@ without a tracer stay effectively free (< a microsecond per span; see
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import os
 import threading
 import time
 from typing import Any, Dict, List, Optional
 
+from repro.obs import jaxprof
+
 __all__ = ["Span", "Tracer", "NullTracer", "NULL_TRACER"]
+
+#: JAX's compile-pipeline events and the span each is booked as
+COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
+    "/jax/core/compile/backend_compile_duration": "compile.backend",
+}
 
 
 class Span:
@@ -78,7 +103,7 @@ class _SpanCtx:
     it instead of timing the region twice."""
 
     __slots__ = ("_tracer", "name", "attrs", "_t0", "dur", "span_id",
-                 "parent_id")
+                 "parent_id", "_note")
 
     def __init__(self, tracer: "Tracer", name: str,
                  attrs: Optional[Dict[str, Any]]):
@@ -88,6 +113,7 @@ class _SpanCtx:
         self.dur = 0.0
         self.span_id = 0
         self.parent_id = None
+        self._note = None
 
     def __enter__(self) -> "_SpanCtx":
         tr = self._tracer
@@ -95,6 +121,10 @@ class _SpanCtx:
         self.parent_id = stack[-1].span_id if stack else None
         self.span_id = next(tr._ids)
         stack.append(self)
+        if jaxprof.profiling():
+            import jax
+            self._note = jax.profiler.TraceAnnotation(self.name)
+            self._note.__enter__()
         self._t0 = tr._clock()
         return self
 
@@ -102,10 +132,14 @@ class _SpanCtx:
         tr = self._tracer
         t1 = tr._clock()
         self.dur = t1 - self._t0
+        if self._note is not None:
+            self._note.__exit__(None, None, None)
+            self._note = None
         stack = tr._stack()
         if stack and stack[-1] is self:
             stack.pop()
-        tr._record(self, self._t0 - tr._epoch)
+        tr._book(self.name, self._t0 - tr._epoch, self.dur, None,
+                 self.span_id, self.parent_id, self.attrs)
         return None
 
 
@@ -145,6 +179,11 @@ class Tracer:
         self._counts: Dict[str, int] = {}
         self._tls = threading.local()
         self._ids = itertools.count(1)
+        #: ``watch_jax`` depth, and per compiling thread (by ident) its
+        #: name and the compile phases not yet final (a later phase may
+        #: hold them)
+        self._watching = 0
+        self._compiles: Dict[int, tuple] = {}
         for s in sinks or ():
             self.add_sink(s)
 
@@ -179,17 +218,90 @@ class Tracer:
             stack = self._tls.stack = []
         return stack
 
-    def _record(self, ctx: _SpanCtx, ts: float) -> None:
-        name = ctx.name
+    def _book(self, name: str, ts: float, dur: float, tid: Optional[str],
+              span_id: int, parent_id: Optional[int],
+              attrs: Optional[Dict[str, Any]]) -> None:
+        """Book one closed span: the aggregates, then every sink.  The
+        one entry point for spans, live (``_SpanCtx``) or reported after
+        the fact (JAX's compile events).  ``tid=None``: this thread."""
         with self._lock:
-            self._totals[name] = self._totals.get(name, 0.0) + ctx.dur
+            self._totals[name] = self._totals.get(name, 0.0) + dur
             self._counts[name] = self._counts.get(name, 0) + 1
             sinks = tuple(self._sinks)
         if sinks:
-            ev = Span(name, ts, ctx.dur, threading.current_thread().name,
-                      ctx.span_id, ctx.parent_id, ctx.attrs).to_event()
+            ev = Span(name, ts, dur,
+                      tid or threading.current_thread().name, span_id,
+                      parent_id, attrs).to_event()
             for s in sinks:
                 s.emit(ev)
+
+    # -- JAX compiles ------------------------------------------------------
+    @contextlib.contextmanager
+    def watch_jax(self):
+        """Book JAX's compile-pipeline events (``COMPILE_SPANS``) as
+        spans while open.  One ``jax.monitoring`` listener per tracer,
+        registered by the outermost ``watch_jax`` and removed by it."""
+        import jax.monitoring
+
+        with self._lock:
+            self._watching += 1
+            first = self._watching == 1
+            # a watched run has its compile spans, at zero if none ran
+            for name in COMPILE_SPANS.values():
+                self._totals.setdefault(name, 0.0)
+                self._counts.setdefault(name, 0)
+        if first:
+            jax.monitoring.register_event_time_span_listener(
+                self._on_compile)
+        try:
+            yield self
+        finally:
+            with self._lock:
+                self._watching -= 1
+                last = self._watching == 0
+            if last:
+                jax.monitoring.unregister_event_time_span_listener(
+                    self._on_compile)
+                with self._lock:
+                    held, self._compiles = self._compiles, {}
+                for tid, pending in held.values():
+                    self._book_compiles(tid, pending)
+
+    def _on_compile(self, event: str, start: float, end: float,
+                    fun_name: Any = None, **_kw) -> None:
+        """JAX's listener: called on the compiling thread when a phase
+        ends, with the phase's ``time.time()`` start and end.  Phases
+        this one holds (they started at or after it) are dropped: their
+        time is this one's.  A backend compile ends a pipeline, so what
+        the thread holds then is final and is booked.  Kept lean: a
+        kernel's trace alone reports a few hundred nested phases."""
+        name = COMPILE_SPANS.get(event)
+        if name is None:
+            return
+        stack = self._stack()
+        parent = stack[-1].span_id if stack else None
+        ident = threading.get_ident()
+        with self._lock:
+            held = self._compiles.get(ident)
+            if held is None:
+                held = self._compiles[ident] = (
+                    threading.current_thread().name, [])
+            pending = held[1]
+            while pending and pending[-1][0] >= start:
+                pending.pop()
+            pending.append((start, end, name, fun_name, parent))
+            if name != "compile.backend":
+                return
+            del self._compiles[ident]
+        self._book_compiles(*held)
+
+    def _book_compiles(self, tid: str, pending: list) -> None:
+        # JAX's wall clock → this tracer's clock
+        shift = self._clock() - self._epoch - time.time()
+        for start, end, name, fun, parent in pending:
+            self._book(name, start + shift, end - start, tid,
+                       next(self._ids), parent,
+                       {"fun": fun} if fun is not None else None)
 
     def event(self, name: str, **attrs) -> None:
         """Emit a zero-duration instant event (sinks only — it does not
@@ -235,6 +347,9 @@ class NullTracer:
 
     def event(self, name: str, **attrs) -> None:
         return None
+
+    def watch_jax(self):
+        return contextlib.nullcontext(self)
 
     def add_sink(self, sink) -> None:
         raise ValueError("NullTracer cannot emit — use a Tracer")

@@ -291,6 +291,32 @@ def test_device_steps_mode(tmp_path):
     assert np.asarray(g.src).max() < 2 ** FIT.n
 
 
+def test_device_steps_compile_and_fetch_spans_nest_in_the_step(tmp_path):
+    """The mesh step compiles inside the first ``struct.device_step``
+    (booked as ``compile.*`` spans there, no set-up span pretends to
+    time it), and each step's copy back is one ``struct.fetch``."""
+    from repro.obs import MemorySink, MetricsRegistry, Tracer
+
+    sink, metrics = MemorySink(), MetricsRegistry()
+    job = DatasetJob(FIT, str(tmp_path / "ds"), shard_edges=16_384, seed=0,
+                     mode="device_steps", tracer=Tracer([sink]),
+                     metrics=metrics)
+    manifest = job.run(max_shards=2)
+    by_id = {e["id"]: e for e in sink.spans()}
+    steps = sink.spans("struct.device_step")
+    assert len(steps) == 2 and not sink.spans("struct.compile")
+    backend = sink.spans("compile.backend")
+    assert any(by_id[e["parent"]]["name"] == "struct.device_step"
+               for e in backend)
+    fetches = sink.spans("struct.fetch")
+    assert [by_id[e["parent"]]["name"] for e in fetches] \
+        == ["struct.device_step"] * 2
+    assert sorted(e["args"]["shard"] for e in fetches) == sorted(
+        r.shard_id for r in manifest.shards if r.status == "done")
+    assert metrics.counter("struct.bytes_fetched").value == sum(
+        e["args"]["bytes"] for e in fetches) > 0
+
+
 # -- per-shard features ------------------------------------------------------
 
 def _fitted_feature_spec(rng):
@@ -616,6 +642,41 @@ def test_deep_verify_streams_blocks_and_catches_corruption(
 
 
 # -- pump --------------------------------------------------------------------
+
+def test_chunk_job_spans_and_counts_each_fetch(tmp_path, monkeypatch):
+    """Every device-to-host copy of the chunked struct stage is one
+    ``struct.fetch`` span under ``struct``; ``struct.chunks`` counts the
+    chunks dispatched and ``struct.bytes_fetched`` what the copies
+    returned."""
+    from repro.obs import MemorySink, MetricsRegistry, Tracer
+
+    returned = []
+    device_get = jax.device_get
+
+    def counting_get(tree):
+        host = device_get(tree)
+        returned.append(sum(x.nbytes for x in jax.tree.leaves(host)))
+        return host
+
+    monkeypatch.setattr(jax, "device_get", counting_get)
+    sink, metrics = MemorySink(), MetricsRegistry()
+    job = DatasetJob(FIT, str(tmp_path / "ds"), shard_edges=8192, seed=0,
+                     backend="xla", tracer=Tracer([sink]), metrics=metrics)
+    manifest = job.run(max_shards=3)
+    done = [r for r in manifest.shards if r.status == "done"]
+    n_chunks = sum(len(r.chunk_indices) for r in done)
+    assert len(done) == 3 and n_chunks > 3
+    assert metrics.counter("struct.chunks").value == n_chunks
+    fetched = metrics.counter("struct.bytes_fetched").value
+    assert fetched == sum(returned) == 8 * sum(r.n_edges for r in done)
+    by_id = {e["id"]: e for e in sink.spans()}
+    fetches = sink.spans("struct.fetch")
+    assert len(fetches) == n_chunks
+    assert all(by_id[e["parent"]]["name"] == "struct" for e in fetches)
+    assert sorted(e["args"]["chunk"] for e in fetches) == sorted(
+        i for r in done for i in r.chunk_indices)
+    assert sum(e["args"]["bytes"] for e in fetches) == fetched
+
 
 def test_pump_chunks_order_and_completeness():
     items = list(range(7))

@@ -11,6 +11,7 @@ import threading
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -231,6 +232,206 @@ def test_jax_profile_start_failure_raises(monkeypatch, tmp_path):
         with jaxprof.trace(str(tmp_path)):
             pass
     assert not jaxprof.profiling()
+
+
+# -- JAX compiles as spans, spans as profiler annotations -------------------
+
+_BACKEND = "/jax/core/compile/backend_compile_duration"
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+
+
+class _JaxEvents:
+    """The test's own count of JAX's compile events (duration listener,
+    so independent of the tracer's time-span listener)."""
+
+    def __init__(self):
+        self.events = []
+
+    def __call__(self, event, dur, **kw):
+        if event.startswith("/jax/core/compile/"):
+            self.events.append((event, dur, kw.get("fun_name")))
+
+    def __enter__(self):
+        jax.monitoring.register_event_duration_secs_listener(self)
+        return self
+
+    def __exit__(self, *exc):
+        jax.monitoring.unregister_event_duration_listener(self)
+
+    def of(self, event):
+        return [e for e in self.events if e[0] == event]
+
+
+def _time_span_listeners():
+    from jax._src import monitoring
+    return len(monitoring.get_event_time_span_listeners())
+
+
+def test_watch_jax_books_compiles_under_the_open_span():
+    sink = MemorySink()
+    tr = Tracer([sink])
+
+    def fresh_program(x):
+        return jnp.sin(x) * 3 + 1
+
+    with _JaxEvents() as seen, tr.watch_jax():
+        with tr.span("struct.dispatch", chunk=0) as sp:
+            jax.block_until_ready(jax.jit(fresh_program)(jnp.ones(17)))
+    backend = sink.spans("compile.backend")
+    lower = sink.spans("compile.lower")
+    assert len(backend) == len(seen.of(_BACKEND)) >= 1
+    mine = [e for e in backend + lower
+            if "fresh_program" in e["args"]["fun"]]
+    assert {e["name"] for e in mine} == {"compile.lower", "compile.backend"}
+    assert all(e["parent"] == sp.span_id for e in mine)
+    # closed spans on the caller's thread, inside the span's interval
+    outer = sink.spans("struct.dispatch")[0]
+    for e in mine:
+        assert e["tid"] == threading.current_thread().name
+        assert outer["ts"] - 1e-3 <= e["ts"]
+        assert e["ts"] + e["dur"] <= outer["ts"] + outer["dur"] + 1e-3
+    assert tr.count("compile.backend") == len(backend)
+    assert tr.total("compile.backend") == pytest.approx(
+        sum(e["dur"] for e in backend))
+
+
+def test_watch_jax_trace_total_is_the_union_of_nested_traces():
+    """An eager Pallas call traces hundreds of primitives inside its own
+    ``wrapped`` trace (and again while lowering); the booked trace time
+    is the outer trace's, not the sum, and the sink gets one span."""
+    from repro.core.sampler import get_backend
+
+    be = get_backend("pallas_bits")
+    th = np.tile(np.array([[0.57, 0.19, 0.19, 0.05]], np.float32), (8, 1))
+    # compile the bit draws first: only the kernel's program is left
+    jax.block_until_ready(be.sample_parts(jax.random.PRNGKey(0), th, 8, 8,
+                                          1024)[0].lo)
+    sink = MemorySink()
+    tr = Tracer([sink])
+    with _JaxEvents() as seen, tr.watch_jax():
+        with tr.span("struct.dispatch"):
+            out = be.sample_parts(jax.random.PRNGKey(1), th, 8, 8, 1024)
+    jax.block_until_ready(out[0].lo)
+    traces = seen.of(_TRACE)
+    outermost = [d for _, d, fun in traces if fun == "wrapped"]
+    assert len(outermost) == 1 and len(traces) > 100
+    total = tr.total("compile.trace")
+    assert 0 < total <= outermost[0] + 1e-9
+    assert total < sum(d for _, d, _ in traces)
+    spans = sink.spans("compile.trace")
+    assert [e["args"]["fun"] for e in spans] == ["wrapped"]
+    assert [e["args"]["fun"] for e in sink.spans("compile.backend")] \
+        == [fun for _, _, fun in seen.of(_BACKEND)]
+    # the phases do not overlap, so together they fit in the dispatch
+    phases = sum(tr.total(n) for n in ("compile.trace", "compile.lower",
+                                       "compile.backend"))
+    assert phases <= tr.total("struct.dispatch")
+
+
+def test_watch_jax_stops_booking_on_exit_and_null_tracer_registers_nothing():
+    tr = Tracer()
+    n0 = _time_span_listeners()
+    with tr.watch_jax():
+        with tr.watch_jax():                # re-entered: one listener
+            assert _time_span_listeners() == n0 + 1
+        assert _time_span_listeners() == n0 + 1
+    assert _time_span_listeners() == n0
+    booked = tr.count("compile.backend")
+
+    def after_exit(x):
+        return x * 5 - 2
+
+    with _JaxEvents() as seen:
+        jax.block_until_ready(jax.jit(after_exit)(jnp.ones(9)))
+    assert seen.of(_BACKEND)
+    assert tr.count("compile.backend") == booked
+    assert tr.total("compile.trace") == 0.0
+
+    with NULL_TRACER.watch_jax():
+        assert _time_span_listeners() == n0
+    assert NULL_TRACER.totals() == {}
+
+
+class _Annotations:
+    """Stand-in for ``jax.profiler.TraceAnnotation``: records each
+    range's name, thread and whether it closed."""
+
+    def __init__(self):
+        self.opened = []
+        self.closed = []
+
+    def __call__(self, name):
+        rec = self
+
+        class Ann:
+            def __enter__(self):
+                rec.opened.append((name, threading.current_thread().name))
+                return self
+
+            def __exit__(self, *exc):
+                rec.closed.append(name)
+
+        return Ann()
+
+
+def test_spans_open_trace_annotations_only_while_profiling(monkeypatch,
+                                                           tmp_path):
+    from repro.obs import jaxprof
+
+    anns = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", anns)
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda d: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    tr = Tracer()
+    with tr.span("struct"):
+        pass
+    assert anns.opened == []
+
+    def worker():
+        with tr.span("write", shard=1):
+            pass
+
+    with jaxprof.trace(str(tmp_path)):
+        with tr.span("struct"):
+            with tr.span("struct.fetch", chunk=0):
+                pass
+        t = threading.Thread(target=worker, name="shard-flush")
+        t.start()
+        t.join()
+        with NULL_TRACER.span("feat"):
+            pass
+    me = threading.current_thread().name
+    assert anns.opened == [("struct", me), ("struct.fetch", me),
+                           ("write", "shard-flush")]
+    assert sorted(anns.closed) == ["struct", "struct.fetch", "write"]
+    with tr.span("struct"):
+        pass
+    assert len(anns.opened) == 3
+
+
+def test_report_run_puts_compiles_in_a_compile_row_outside_busy_stages():
+    sink = MemorySink()
+    tr = Tracer([sink])
+
+    def reported_program(x):
+        return jnp.cos(x) - 4
+
+    with tr.watch_jax(), tr.span("run"):
+        with tr.span("struct", shard=0):
+            jax.block_until_ready(jax.jit(reported_program)(jnp.ones(11)))
+    report_run = _load_script("report_run")
+    rep = report_run.summarize(sink.events)
+    phases = {n: tr.total(f"compile.{n}")
+              for n in ("trace", "lower", "backend")}
+    assert phases["backend"] > 0
+    assert rep["compile"]["total_s"] == pytest.approx(sum(phases.values()))
+    for n, v in phases.items():
+        assert rep["compile"][n] == pytest.approx(v)
+    assert not any(k.startswith("compile") for k in report_run.BUSY_STAGES)
+    assert rep["busy_s"] == pytest.approx(tr.total("struct"))
+    row = [ln for ln in report_run.format_report(rep).splitlines()
+           if ln.startswith("compile ")]
+    assert len(row) == 1 and "backend" in row[0]
 
 
 # -- chrome trace export -----------------------------------------------------
