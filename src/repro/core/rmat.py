@@ -14,6 +14,7 @@ Node ids follow the engine's dtype contract: int32 up to 31 bits, int64
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import List, NamedTuple, Optional, Tuple
 
@@ -22,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import sampler as sampler_mod
-from repro.core.descend import check_id_capacity
+from repro.core.descend import check_id_capacity, narrow_ids
 from repro.core.structure import KroneckerFit, noisy_thetas
 
 
@@ -36,9 +37,13 @@ def sample_edges(key, thetas, n: int, m: int, n_edges: int,
     ``backend=None`` keeps the ``xla`` reference stream (bit-stable across
     repo versions); pass a registry name or ``'auto'`` to switch engines.
     """
-    be = sampler_mod.get_backend("xla") if backend is None \
+    return _engine(backend, n_edges).sample(key, thetas, n, m, n_edges,
+                                            id_dtype=dtype)
+
+
+def _engine(backend: Optional[str], n_edges: int):
+    return sampler_mod.get_backend("xla") if backend is None \
         else sampler_mod.resolve_backend(backend, n_edges)
-    return be.sample(key, thetas, n, m, n_edges, id_dtype=dtype)
 
 
 _NOISE_SALT = 0x5eed
@@ -135,15 +140,35 @@ def chunk_plan(fit: KroneckerFit, k_pref: int,
             for s, d, e, i in zip(sp, dp, base[nz], nz)]
 
 
+def suffix_thetas(thetas, k_pref: int):
+    """θ's suffix levels (rows ``k_pref`` on) as a device float32 array,
+    made once per distinct θ: a chunk call then copies no θ to the
+    device, and every caller hands the compiled chunk programs the same
+    array."""
+    host = np.asarray(thetas, np.float32)[k_pref:]
+    return _device_rows(host.tobytes(), len(host))
+
+
+@functools.lru_cache(maxsize=8)
+def _device_rows(raw: bytes, rows: int):
+    return jnp.asarray(np.frombuffer(raw, np.float32).reshape(rows, 4))
+
+
 def sample_chunk(key, fit: KroneckerFit, chunk: Chunk, k_pref: int,
                  thetas=None, dtype=jnp.int32,
-                 backend: Optional[str] = None):
+                 backend: Optional[str] = None, padded: bool = False):
     """Sample one chunk: suffix levels from θ_gen, prefix bits prepended.
     Guaranteed id-disjoint across chunks (distinct prefixes).
 
     ``thetas`` must be derived ONCE by the caller (``derive_thetas``) and
     threaded through every chunk of a generation; for noiseless fits it is
     optional (the deterministic base is used).
+
+    Narrow ids come back as device int32 arrays with the prefix added on
+    device, in the backend's one dispatch where it can; ``padded=True``
+    leaves them as long as the backend made them (kernel blocks past
+    ``chunk.n_edges``), for a caller that trims them on the host after
+    the copy.  Wide ids come back as host numpy arrays.
     """
     # prefix bits + suffix level bits must fit the id dtype — raise
     # instead of wrapping (int32 silently capped ids at 2^31 before)
@@ -156,20 +181,23 @@ def sample_chunk(key, fit: KroneckerFit, chunk: Chunk, k_pref: int,
                 "caller and pass thetas= — a per-call default rng would "
                 "silently reuse identical θ-noise across chunks")
         thetas = derive_thetas(fit)
-    suffix = jnp.asarray(np.asarray(thetas)[k_pref:], jnp.float32)
+    suffix = suffix_thetas(thetas, k_pref)
     n_s, m_s = fit.n - k_pref, fit.m - k_pref
-    src, dst = sample_edges(key, suffix, n_s, m_s, chunk.n_edges, dtype,
-                            backend)
-    # int64 prefix arithmetic happens in host numpy (x64-independent);
-    # narrow stays on device
     dt = np.dtype(dtype)
     if dt.itemsize > 4:
-        src = np.asarray(src) + dt.type(chunk.src_prefix << n_s)
-        dst = np.asarray(dst) + dt.type(chunk.dst_prefix << m_s)
-    else:
-        src = src + (chunk.src_prefix << n_s)
-        dst = dst + (chunk.dst_prefix << m_s)
-    return src, dst
+        # int64 prefix arithmetic happens in host numpy (x64-independent)
+        src, dst = sample_edges(key, suffix, n_s, m_s, chunk.n_edges, dtype,
+                                backend)
+        return (np.asarray(src) + dt.type(chunk.src_prefix << n_s),
+                np.asarray(dst) + dt.type(chunk.dst_prefix << m_s))
+    prefix = np.array([chunk.src_prefix << n_s, chunk.dst_prefix << m_s],
+                      np.int32)
+    src, dst = _engine(backend, chunk.n_edges).sample_chunk_parts(
+        key, suffix, n_s, m_s, chunk.n_edges, prefix)
+    if padded:
+        return src.lo, dst.lo
+    return (narrow_ids(src, chunk.n_edges, dt),
+            narrow_ids(dst, chunk.n_edges, dt))
 
 
 def sample_graph_chunked(key, fit: KroneckerFit, k_pref: int = 2,

@@ -89,6 +89,13 @@ def _finalize(src: IdParts, dst: IdParts, n: int, m: int, dt: np.dtype,
             combine_ids(dst, m, dt)[:n_edges])
 
 
+def _add_prefix(parts: Tuple[IdParts, IdParts], prefix
+                ) -> Tuple[IdParts, IdParts]:
+    src, dst = parts
+    return (src._replace(lo=src.lo + prefix[0]),
+            dst._replace(lo=dst.lo + prefix[1]))
+
+
 class EdgeSamplerBackend:
     """One way of turning ``(key, thetas, n, m, n_edges)`` into edges."""
 
@@ -107,6 +114,14 @@ class EdgeSamplerBackend:
         overlap device generation with host I/O (``pump_chunks``) fetch
         and ``descend.combine_ids`` these on their own schedule."""
         raise NotImplementedError
+
+    def sample_chunk_parts(self, key, thetas, n: int, m: int,
+                           n_edges: int, prefix) -> Tuple[IdParts, IdParts]:
+        """``sample_parts`` of one chunk of narrow ids, with ``prefix`` —
+        the chunk's int32 ``(src, dst)`` id prefixes, already shifted past
+        the ``n`` and ``m`` level bits — added to the low words."""
+        return _add_prefix(self.sample_parts(key, thetas, n, m, n_edges),
+                           prefix)
 
     def sample(self, key, thetas, n: int, m: int, n_edges: int,
                id_dtype=np.int32) -> Tuple[np.ndarray, np.ndarray]:
@@ -174,6 +189,22 @@ class PallasBitsBackend(EdgeSamplerBackend):
 # pallas_prng: bits generated in VMEM (TPU-only)
 # ---------------------------------------------------------------------------
 
+@functools.partial(jax.jit, static_argnames=("n", "m", "n_pad", "block",
+                                             "interpret"))
+def _prng_chunk(key, thetas, prefix, *, n: int, m: int, n_pad: int,
+                block: int, interpret: bool):
+    """All of one chunk's device work: per-block seeds, the kernel, and
+    (``prefix`` not None) the chunk's id prefixes.  One program per
+    padded shape: the chunk's exact size is not an argument, so every
+    chunk that pads to ``(n_pad, block)`` reuses it (an eager
+    ``pallas_call`` compiles anew on every call)."""
+    src, dst = rs.rmat_sample_prng(
+        rs.prng_block_seeds(key, n_pad // block), thetas, n, m, n_pad,
+        block=block, interpret=pltpu.InterpretParams() if interpret
+        else False)
+    return (src, dst) if prefix is None else _add_prefix((src, dst), prefix)
+
+
 class PallasPrngBackend(EdgeSamplerBackend):
     name = "pallas_prng"
 
@@ -193,18 +224,21 @@ class PallasPrngBackend(EdgeSamplerBackend):
         return None
 
     def sample_parts(self, key, thetas, n, m, n_edges):
+        return self._chunk(key, thetas, n, m, n_edges, None)
+
+    def sample_chunk_parts(self, key, thetas, n, m, n_edges, prefix):
+        return self._chunk(key, thetas, n, m, n_edges, prefix)
+
+    def _chunk(self, key, thetas, n, m, n_edges, prefix):
         reason = self.why_unavailable()
         if reason is not None:
             raise RuntimeError(f"backend 'pallas_prng' unavailable: "
                                f"{reason}; use 'pallas_bits' or 'xla'")
         block = choose_block(n_edges)
-        n_pad = _pad_edges(n_edges, block)
-        interpret = (pltpu.InterpretParams()
-                     if jax.default_backend() != "tpu" else False)
-        return rs.rmat_sample_prng(rs.prng_block_seeds(key, n_pad // block),
-                                   jnp.asarray(thetas, jnp.float32),
-                                   n, m, n_pad, block=block,
-                                   interpret=interpret)
+        return _prng_chunk(key, jnp.asarray(thetas, jnp.float32), prefix,
+                           n=n, m=m, n_pad=_pad_edges(n_edges, block),
+                           block=block,
+                           interpret=jax.default_backend() != "tpu")
 
 
 # ---------------------------------------------------------------------------

@@ -358,10 +358,14 @@ class ChunkShardSource(ShardSource):
     def _generate_staged(self, rec: ShardRecord) -> Dict[str, np.ndarray]:
         """Double-buffered chunk loop into a preallocated shard buffer.
 
-        Wide (int64) ids dispatch the backend's device-resident
-        ``(hi, lo)`` id words and combine them host-side in ``flush`` —
-        combining inside dispatch would force a device sync per chunk
-        and silently serialize the double-buffered pump."""
+        A chunk's dispatch is one call of the backend's compiled chunk
+        program where it has one: its ids stay on the device, padded
+        past the chunk (kernel blocks), and ``flush`` trims them as it
+        copies them into the shard buffer.  Narrow ids get their prefix
+        on the device; wide (int64) ids combine their ``(hi, lo)`` words
+        and prefix in ``flush`` — combining inside dispatch would force a
+        device sync per chunk and silently serialize the double-buffered
+        pump."""
         sched = self.scheduler
         np_dtype = self.dtype
         src_buf = np.empty(rec.n_edges, np_dtype)
@@ -372,7 +376,7 @@ class ChunkShardSource(ShardSource):
         wide = np_dtype.itemsize > 4
         if wide:
             be = get_backend(self.backend)
-            suffix = np.asarray(sched.thetas)[sched.k_pref:]
+            suffix = rmat.suffix_thetas(sched.thetas, sched.k_pref)
             n_s = self.fit.n - sched.k_pref
             m_s = self.fit.m - sched.k_pref
 
@@ -387,21 +391,22 @@ class ChunkShardSource(ShardSource):
                 return rmat.sample_chunk(sched.key_for(ck), self.fit,
                                          ck, sched.k_pref,
                                          sched.thetas, dtype=np_dtype,
-                                         backend=self.backend)
+                                         backend=self.backend, padded=True)
 
         def flush(ck, host):
             off = offsets[ck.index]
             with self.tracer.span("struct.combine", chunk=ck.index):
                 if wide:
-                    sparts, dparts = host  # backend may pad past n_edges
+                    sparts, dparts = host
                     s = combine_ids(sparts, n_s, np_dtype,
-                                    prefix=ck.src_prefix)[: ck.n_edges]
+                                    prefix=ck.src_prefix)
                     d = combine_ids(dparts, m_s, np_dtype,
-                                    prefix=ck.dst_prefix)[: ck.n_edges]
+                                    prefix=ck.dst_prefix)
                 else:
                     s, d = host
-                src_buf[off: off + ck.n_edges] = s
-                dst_buf[off: off + ck.n_edges] = d
+                # the backend may pad past n_edges
+                src_buf[off: off + ck.n_edges] = s[: ck.n_edges]
+                dst_buf[off: off + ck.n_edges] = d[: ck.n_edges]
 
         pump_chunks(chunks, dispatch, flush,
                     double_buffered=self.double_buffered,

@@ -678,6 +678,75 @@ def test_chunk_job_spans_and_counts_each_fetch(tmp_path, monkeypatch):
     assert sum(e["args"]["bytes"] for e in fetches) == fetched
 
 
+def test_staged_pallas_prng_traces_once_per_padded_shape(
+        tmp_path, monkeypatch, prng_chunk_program):
+    """The staged chunk source compiles ``pallas_prng``'s chunk program
+    once per padded shape, a second job over the same shards compiles
+    nothing, and the shards are byte-identical to the eager composition
+    the program replaced."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.core import sampler
+    from repro.kernels import rmat_sample as rs
+
+    class EagerPrng(sampler.PallasPrngBackend):
+        # the prefix added by an eager op after the kernel's own dispatch
+        sample_chunk_parts = sampler.EdgeSamplerBackend.sample_chunk_parts
+
+        def sample_parts(self, key, thetas, n, m, n_edges):
+            block = sampler.choose_block(n_edges)
+            n_pad = sampler._pad_edges(n_edges, block)
+            return rs.rmat_sample_prng(
+                rs.prng_block_seeds(key, n_pad // block),
+                jax.numpy.asarray(thetas, jax.numpy.float32), n, m, n_pad,
+                block=block, interpret=pltpu.InterpretParams())
+
+    fit = KroneckerFit(a=0.45, b=0.22, c=0.2, d=0.13, n=10, m=10, E=6000)
+    monkeypatch.setitem(sampler._REGISTRY, "pallas_prng",
+                        sampler.PallasPrngBackend(force_interpret=True))
+
+    def run(name):
+        job = DatasetJob(fit, str(tmp_path / name), shard_edges=2048,
+                         seed=3, backend="pallas_prng")
+        job.run()
+        return job
+
+    job = run("first")
+    sched = job.scheduler
+    shapes = {(sampler.choose_block(ck.n_edges),
+               sampler._pad_edges(ck.n_edges,
+                                  sampler.choose_block(ck.n_edges)))
+              for ck in sched.chunks}
+    assert len(sched.shards) > 1 and 1 < len(shapes) < len(
+        {ck.n_edges for ck in sched.chunks})
+    assert prng_chunk_program.total("_prng_chunk") == len(shapes)
+    traced = prng_chunk_program.total()
+    # the benchmark's warm-up route, rmat.sample_chunk, reaches the same
+    # compiled programs, and trims and prefixes the ids on device to
+    # the values the shards hold
+    ds = ShardedGraphDataset(str(tmp_path / "first"))
+    for rec in sched.shards[:2]:
+        cols = [rmat.sample_chunk(sched.key_for(sched.chunk(i)), fit,
+                                  sched.chunk(i), sched.k_pref,
+                                  sched.thetas, backend="pallas_prng")
+                for i in rec.chunk_indices]
+        got = ds.load_shard(rec.shard_id)
+        np.testing.assert_array_equal(
+            got.src, np.concatenate([np.asarray(s) for s, _ in cols]))
+        np.testing.assert_array_equal(
+            got.dst, np.concatenate([np.asarray(d) for _, d in cols]))
+    assert prng_chunk_program.total("_prng_chunk") == len(shapes)
+    run("second")
+    assert prng_chunk_program.total() == traced
+    monkeypatch.setitem(sampler._REGISTRY, "pallas_prng",
+                        EagerPrng(force_interpret=True))
+    run("eager")
+    first = _file_hashes(str(tmp_path / "first"))
+    assert len(first) == 2 * len(sched.shards)
+    assert first == _file_hashes(str(tmp_path / "second")) \
+        == _file_hashes(str(tmp_path / "eager"))
+
+
 def test_pump_chunks_order_and_completeness():
     items = list(range(7))
     for dbl in (True, False):

@@ -122,6 +122,53 @@ def test_pallas_prng_forced_interpret_end_to_end():
         sampler._REGISTRY["pallas_prng"] = orig
 
 
+@pytest.mark.parametrize("levels,prefixed", [(18, True), (18, False),
+                                             (34, False)])
+def test_pallas_prng_compiles_once_per_padded_shape(
+        levels, prefixed, prng_chunk_program, prng_chunk_compiles):
+    """A chunk's seeds, kernel and (narrow ids) prefix add are one
+    program per padded shape: sizes that pad to the same
+    ``(n_pad, block)`` reuse it, and its ids equal the eager composition
+    it replaced, bit for bit (the interpreter's PRNG yields zero bits,
+    so off-TPU this pins the plumbing; the chip pins the stream)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.kernels import rmat_sample as rs
+    forced = sampler.PallasPrngBackend(force_interpret=True)
+    sizes = (5000, 6000, 5000, 8192)     # all one 8192-edge block
+    assert {sampler._pad_edges(n, sampler.choose_block(n))
+            for n in sizes} == {8192}
+    th = _tiled_thetas(levels)
+    keys = [jax.random.fold_in(jax.random.PRNGKey(7), i)
+            for i in range(len(sizes))]
+    # narrow ids only: a chunk's prefixes above its levels
+    prefixes = [np.array([3 * i << levels, (5 * i + 1) << levels],
+                         np.int32) if prefixed else None
+                for i in range(len(sizes))]
+    if prefixed:
+        parts = [forced.sample_chunk_parts(k, th, levels, levels, n, p)
+                 for k, n, p in zip(keys, sizes, prefixes)]
+    else:
+        parts = [forced.sample_parts(k, th, levels, levels, n)
+                 for k, n in zip(keys, sizes)]
+    jax.block_until_ready(parts)
+    assert len(prng_chunk_compiles) == 1
+    assert prng_chunk_program.total("_prng_chunk") == 1
+    for k, p, (src, dst) in zip(keys[:2], prefixes, parts):
+        want = rs.rmat_sample_prng(rs.prng_block_seeds(k, 1), th, levels,
+                                   levels, 8192, block=8192,
+                                   interpret=pltpu.InterpretParams())
+        if prefixed:
+            want = [w._replace(lo=w.lo + off) for w, off in zip(want, p)]
+        assert (src.hi is None) == (levels <= LO_BITS)
+        for got, ref_ in zip((*src, *dst), (*want[0], *want[1])):
+            assert (got is None) == (ref_ is None)
+            if got is not None:
+                assert got.shape == (8192,) and got.dtype == jnp.int32
+                np.testing.assert_array_equal(np.asarray(got),
+                                              np.asarray(ref_))
+
+
 def test_xla_backend_is_the_sample_edges_stream():
     """The engine's xla backend reproduces the PRE-ENGINE
     ``rmat.sample_edges`` stream bit-for-bit (the invariant that lets

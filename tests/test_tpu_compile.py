@@ -74,6 +74,24 @@ def test_rmat_sample_prng_compiles_for_v5e(one_chip, levels):
         _n_outputs(levels) * 4 * N_EDGES
 
 
+@pytest.mark.parametrize("levels", [18, 34])
+def test_prng_chunk_program_compiles_for_v5e(one_chip, levels):
+    """``pallas_prng``'s per-chunk program (seeds, kernel and, for narrow
+    ids, the prefix add in one) at the Graph500 cell's suffix depth and
+    at wide ids."""
+    from repro.core import sampler
+    prefix = (None if levels > 31
+              else _abstract((2,), jnp.int32, one_chip))
+    compiled = sampler._prng_chunk.lower(
+        _abstract((2,), jnp.uint32, one_chip),
+        _abstract((levels, 4), jnp.float32, one_chip), prefix,
+        n=levels, m=levels, n_pad=N_EDGES, block=BLOCK,
+        interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().output_size_in_bytes >= \
+        _n_outputs(levels) * 4 * N_EDGES
+
+
 @pytest.mark.parametrize("levels", [30, 34])
 def test_rmat_sample_bits_compiles_for_v5e(one_chip, levels):
     fn = functools.partial(rs.rmat_sample_bits, n=levels, m=levels,
