@@ -24,9 +24,9 @@ d. attributed transactions: a kronecker + GAN + GBDT-aligner pipeline
    the fused path with deep verify, and its first shard equals the
    staged path's byte for byte.
 
-With ``--chips 4`` it runs only the ``device_steps`` path on a 2x2 mesh
-(2^24 edges per step) and compares every step with a one-device replay
-of each device's stream.
+With ``--chips 4`` it runs only the ``device_steps`` path on a 1-D mesh
+of the four chips (2^24 edges per step) and compares every step with a
+one-device replay of each device's stream.
 
 Each phase prints its result and wall time on a line of its own; the
 last line of standard output is the JSON object
@@ -208,30 +208,30 @@ def phase_featured(work: str, min_edges: int = FEATURED_EDGES,
 
 # -- --chips 4: device_steps on the mesh -------------------------------------
 
-def replay_step(seeds, thetas, n_loc: int, m: int, edges_per_device: int,
+def replay_step(seeds, thetas, n: int, m: int, edges_per_device: int,
                 dtype):
     """One device_steps step replayed device by device on one device: the
-    same threefry keys per device seed and the device index as the src
+    same threefry keys per device seed and the same law
+    (``distributed_gen.device_block``) with the device index as the src
     prefix, outside any mesh."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.core.descend import combine_ids_device, descend
+    from repro.core.distributed_gen import device_block
 
     thetas = jnp.asarray(thetas, jnp.float32)
+    k = int(np.log2(len(seeds)))
 
     @jax.jit
     def one(seed, prefix):
         keys = jax.random.split(
-            jax.random.fold_in(jax.random.PRNGKey(0), seed), max(n_loc, m))
-        src, dst = descend(
+            jax.random.fold_in(jax.random.PRNGKey(0), seed), max(n, m))
+        return device_block(
             lambda ell: jax.random.uniform(keys[ell], (edges_per_device,),
                                            jnp.float32),
-            lambda ell: (thetas[ell, 0], thetas[ell, 1], thetas[ell, 2]),
-            n_loc, m, lambda: jnp.zeros((edges_per_device,), jnp.int32))
-        return (combine_ids_device(src, n_loc, dtype, prefix=prefix),
-                combine_ids_device(dst, m, dtype))
+            thetas, prefix, n, m, k,
+            lambda: jnp.zeros((edges_per_device,), jnp.int32), dtype)
 
     with jax.default_device(jax.devices()[0]):
         parts = [one(jnp.int32(s), jnp.int32(i)) for i, s in enumerate(seeds)]
@@ -274,7 +274,7 @@ def phase_mesh(work: str, chips: int = 4, step_edges: int = MESH_STEP_EDGES,
             src_dev, _ = step(jnp.asarray(seeds))
             spans = src_dev.sharding.device_set
             assert spans == set(jax.devices()), spans
-        src_ref, dst_ref = replay_step(seeds, job.scheduler.thetas, n_loc,
+        src_ref, dst_ref = replay_step(seeds, job.scheduler.thetas, fit.n,
                                        fit.m, epd, np.int32)
         shard = ds.load_shard(rec.shard_id)
         np.testing.assert_array_equal(shard.src, src_ref[: rec.n_edges])

@@ -1,15 +1,20 @@
 """Distributed chunked generation over a TPU mesh (paper App. 10 at pod
 scale).
 
-Each device owns a disjoint set of prefix chunks; one ``generation step``
-produces ``edges_per_device`` edges on every device simultaneously with
-ZERO collectives (the roofline collective term of this step is ~0 by
-construction — the paper's linear multi-GPU scaling claim, reproduced as a
-property of the lowered HLO).
+Each device owns one src id range, the top ``k = log2(n_dev)`` src levels
+being its index; one ``generation step`` produces ``edges_per_device``
+edges on every device simultaneously with ZERO collectives (the roofline
+collective term of this step is ~0 by construction — the paper's linear
+multi-GPU scaling claim, reproduced as a property of the lowered HLO).
+Those k src levels are uniform (every device makes the same number of
+edges); everything else follows the Kronecker law given the device's src
+prefix: the k dst prefix levels are drawn conditioned on the device's src
+bits, and every later src level is drawn jointly with the dst level of
+the same index (``device_block``).
 
-The shard_map body contains no sampling logic of its own: it drives the
-repo-wide shared level-descend core (``repro.core.descend.descend``) and
-composes the device prefix with ``combine_ids_device``.
+The shard_map body contains no level-descend logic of its own: it drives
+the repo-wide shared core (``repro.core.descend.descend``) for the levels
+past the prefix and composes the prefixes with ``combine_ids_device``.
 
 ``build_generation_cell`` returns the lowering target used by
 ``launch/dryrun.py --graphgen``: one streaming step of the trillion-edge
@@ -50,21 +55,57 @@ def step_seeds(base_seed: int, step: int, n_dev: int) -> np.ndarray:
     return (mix & np.uint64(0x7FFFFFFF)).astype(np.int32)
 
 
+def device_block(get_u, thetas, didx, n: int, m: int, k: int, zeros,
+                 dtype):
+    """One device's block of a mesh step: the Kronecker law restricted to
+    src prefix ``didx``, the top ``k`` of the ``n`` src levels.
+
+    Level ``ell`` reads θ row ``ell`` (the ``ChunkScheduler`` convention)
+    and ``get_u(ell)`` (one uniform per edge per level, ``max(n, m)`` in
+    all).  At a prefix level ``ell < k`` the src bit is the device's own
+    bit and the dst bit is drawn conditioned on it, P(dst bit = 0 | s) =
+    θ[ell][s, 0] / (θ[ell][s, 0] + θ[ell][s, 1]); past the prefix the
+    shared ``descend`` draws src bit ``ell`` and dst bit ``ell`` jointly
+    from θ row ``ell``, and levels beyond ``min(n, m)`` from its
+    marginals.  Returns the (src, dst) ids in ``dtype``."""
+    m_pref = min(k, m)                # prefix levels that carry a dst bit
+    dst_pre = None
+    for ell in range(m_pref):
+        sb = (didx >> (k - 1 - ell)) & 1
+        a, b, c, d = (thetas[ell, i] for i in range(4))
+        p0 = jnp.where(sb == 0, a / (a + b), c / (c + d))
+        db = (get_u(ell) >= p0).astype(jnp.int32)
+        dst_pre = db if dst_pre is None else dst_pre * 2 + db
+    src, dst = descend(
+        lambda ell: get_u(ell + k),
+        lambda ell: (thetas[ell + k, 0], thetas[ell + k, 1],
+                     thetas[ell + k, 2]),
+        n - k, m - m_pref, zeros)
+    return (combine_ids_device(src, n - k, dtype, prefix=didx),
+            combine_ids_device(dst, m - m_pref, dtype, prefix=dst_pre))
+
+
 def device_generate(thetas, seeds, n: int, m: int, edges_per_device: int,
                     mesh, dtype=jnp.int32, uniforms=None):
-    """shard_map over every mesh axis: device i samples its chunk with its
-    own fold-in key; prefix bits = device index (id-disjoint chunks).
+    """shard_map over every mesh axis: device ``d`` draws
+    ``edges_per_device`` edges of the ``n``-by-``m``-level graph under src
+    prefix ``d`` (its top ``log2(n_dev)`` src levels), with its own
+    fold-in key and the law of ``device_block``.
 
-    ``uniforms`` (n_dev, L, E) switches to the paper-faithful GPU-port mode
-    where pre-generated uniforms stream from HBM (the §Perf baseline); the
-    default generates threefry bits on-device."""
+    ``thetas`` holds ``max(n, m)`` rows.  ``uniforms`` (n_dev, max(n, m),
+    E) switches to the paper-faithful GPU-port mode where pre-generated
+    uniforms stream from HBM (the §Perf baseline); the default generates
+    threefry bits on-device."""
     axes = tuple(mesh.axis_names)
     n_dev = mesh.size
     k_pref = int(np.log2(n_dev))  # device index becomes a src-prefix
+    if 2 ** k_pref != n_dev or k_pref > n:
+        raise ValueError(f"device_generate: {n_dev} devices must be a power "
+                         f"of two of at most 2^n = 2^{n}")
     dt = np.dtype(dtype)
-    # device prefix bits + level bits must fit the id dtype — raise
-    # instead of wrapping (``didx << n`` silently overflowed for n ≥ 31)
-    check_id_capacity(n + k_pref, dt,
+    # prefix + level bits must fit the id dtype — raise instead of
+    # wrapping (``didx << n`` silently overflowed for n ≥ 31)
+    check_id_capacity(n, dt,
                       "device_generate: device prefix + src level bits")
     check_id_capacity(m, dt, "device_generate: dst level bits")
     if dt.itemsize > 4 and not jax.config.jax_enable_x64:
@@ -82,16 +123,12 @@ def device_generate(thetas, seeds, n: int, m: int, edges_per_device: int,
                 keys[ell], (edges_per_device,), jnp.float32)
         else:
             get_u = lambda ell: u_in[0, ell]                # noqa: E731
-        src, dst = descend(
-            get_u,
-            lambda ell: (thetas[ell, 0], thetas[ell, 1], thetas[ell, 2]),
-            n, m, lambda: jnp.zeros((edges_per_device,), jnp.int32))
-        # prepend device prefix on src (disjoint id ranges per device)
         didx = jnp.zeros((), jnp.int32)
         for ax in axes:
             didx = didx * mesh.shape[ax] + jax.lax.axis_index(ax)
-        src_ids = combine_ids_device(src, n, dt, prefix=didx)
-        dst_ids = combine_ids_device(dst, m, dt)
+        src_ids, dst_ids = device_block(
+            get_u, thetas, didx, n, m, k_pref,
+            lambda: jnp.zeros((edges_per_device,), jnp.int32), dt)
         return src_ids[None], dst_ids[None]
 
     if uniforms is not None:
@@ -128,10 +165,8 @@ def build_generation_cell(mesh, scale: str = "1t",
 
     The device prefix is part of the 2^30 src id space (top ``log2(n_dev)``
     src levels = device index, sampled suffix = the rest), so ids fit
-    int32 on any mesh — the previous layout pushed the prefix *above* 30
-    bits and silently wrapped for ≥ 2 devices."""
-    m = 30          # 2^30 nodes per partite (total, across the mesh)
-    n = m - int(np.log2(mesh.size))   # per-device src suffix levels
+    int32 on any mesh."""
+    n = m = 30      # 2^30 nodes per partite (total, across the mesh)
     L = max(n, m)
     thetas_abs = jax.ShapeDtypeStruct((L, 4), jnp.float32)
     seeds_abs = jax.ShapeDtypeStruct((mesh.size,), jnp.int32)

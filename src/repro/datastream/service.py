@@ -49,12 +49,13 @@ from repro.utils import accepts_kwarg
 
 __all__ = ["DatasetJob", "FeatureSpec"]
 
-#: stream marker recorded for device_steps manifests: the shard_map body
-#: now draws all L level keys with one split (shared descend core), a
-#: different threefry stream than the pre-engine iterative key chain —
-#: resuming an old device_steps dataset must refuse, not silently mix
-#: streams.  Bump when the device stream changes again.
-_DEVICE_STREAM = "device_descend_v2"
+#: stream marker recorded for device_steps manifests.  v3: the mesh step
+#: pairs src level ell with dst level ell past the device prefix and
+#: draws the dst prefix levels conditioned on the device's src bits (v2
+#: paired src level ell + log2(n_dev) with dst level ell) — resuming an
+#: older device_steps dataset must refuse, not silently mix streams.
+#: Bump when the device stream changes again.
+_DEVICE_STREAM = "device_descend_v3"
 
 
 def _edge_dtype(fit: KroneckerFit, id_dtype=None):

@@ -14,8 +14,11 @@ testable:
   (``mode="device_steps"``): one shard = one mesh-wide generation step
   with step-indexed seeds (paper App. 10's zero-collective design).
   Maximum throughput, but every device emits the same edge count under
-  its own src prefix, so the top ``log2(n_dev)`` src levels are uniform
-  rather than θ-distributed.
+  its own src prefix, so the top ``k = log2(n_dev)`` src levels are
+  uniform rather than θ-distributed.  Given that prefix the law is
+  Kronecker's: the k dst prefix levels are drawn conditioned on the
+  device's src bits, and every later level draws its src and dst bits
+  jointly from its θ row.
 
 Either way ``generate(rec)`` is a pure function of
 ``(fit, seed, shard_id)`` — byte-identical on regeneration, which is
@@ -417,7 +420,11 @@ class ChunkShardSource(ShardSource):
 class DeviceStepShardSource(ShardSource):
     """One mesh-wide ``device_generate`` step == one shard; the step index
     (== shard id) seeds the per-device streams, so any step can be
-    regenerated in isolation."""
+    regenerated in isolation.  A shard is the devices' blocks in device
+    order; block ``d`` has src prefix ``d`` over the top ``k =
+    log2(n_dev)`` src levels (uniform across blocks), dst prefix levels
+    drawn conditioned on those src bits, and levels ``k`` and below drawn
+    as joint (src, dst) quadrants from θ rows ``k`` on."""
 
     name = "device_steps"
 
@@ -453,21 +460,14 @@ class DeviceStepShardSource(ShardSource):
 
         mesh = Mesh(np.array(jax.devices()), ("d",))
         n_dev = mesh.size
-        k_dev = int(np.log2(n_dev))
-        if 2 ** k_dev != n_dev:
-            raise ValueError(
-                f"device count {n_dev} must be a power of two")
-        n_loc = self.fit.n - k_dev
         epd = math.ceil(self.shard_edges / n_dev)
-        # full θ rows: the shared descend runs max(n_loc, m) levels
-        # (dst keeps all m levels; only src loses k_dev to the device
-        # prefix), so offsetting rows by k_dev would both starve the
-        # last k_dev dst levels and misalign the square levels.
+        # full θ rows, row ell = level ell (``device_block``), as the
+        # chunk plan reads them
         thetas = jnp.asarray(self.thetas, jnp.float32)
 
         @jax.jit
         def step(seeds):
-            return device_generate(thetas, seeds, n_loc, self.fit.m,
+            return device_generate(thetas, seeds, self.fit.n, self.fit.m,
                                    epd, mesh, dtype=self.dtype)
 
         return (step, n_dev)
@@ -521,13 +521,24 @@ class DeviceStepShardSource(ShardSource):
                     self.features.feature_key_int(self.seed, rec.shard_id))
                 params = self.features.generator.params["g"]
                 fn = self._fused_step(n_blocks, b)
-                (src, dst), (cont, cat) = self._fetch(
-                    fn(seeds, params, fkey), shard=rec.shard_id)
+                out = self._dispatch(fn, rec, seeds, params, fkey)
+                (src, dst), (cont, cat) = self._fetch(out,
+                                                      shard=rec.shard_id)
                 return {"src": np.asarray(src)[: rec.n_edges],
                         "dst": np.asarray(dst)[: rec.n_edges],
                         "cont": np.asarray(cont)[: rec.n_edges],
                         "cat": np.asarray(cat)[: rec.n_edges]}
-            src, dst = self._fetch(step(seeds), shard=rec.shard_id)
+            src, dst = self._fetch(self._dispatch(step, rec, seeds),
+                                   shard=rec.shard_id)
             src = np.asarray(src).reshape(-1)
             dst = np.asarray(dst).reshape(-1)
         return {"src": src[: rec.n_edges], "dst": dst[: rec.n_edges]}
+
+    def _dispatch(self, fn, rec: ShardRecord, *args):
+        """One mesh step's call under a ``struct.dispatch`` span (it holds
+        the step's compile on the first call; the device work runs on
+        after it returns), counted by ``struct.steps``."""
+        with self.tracer.span("struct.dispatch", shard=rec.shard_id):
+            out = fn(*args)
+        self._count("struct.steps", "steps", 1)
+        return out

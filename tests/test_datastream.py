@@ -245,9 +245,13 @@ def test_reader_batches_and_verify(tmp_path):
 
 
 def test_device_steps_multidevice(tmp_path):
-    """device_steps on a >1-device mesh: per-device prefixes cover the id
-    space, dst levels keep full θ rows (noise on would misalign otherwise),
-    and the dataset verifies."""
+    """device_steps on a >1-device mesh: the top k = log2(n_dev) src
+    levels are the device index (uniform over the devices), so the
+    per-device prefixes cover the id space; the k dst prefix levels are
+    drawn conditioned on those src bits from θ rows 0..k-1, and every
+    later level draws its src and dst bits together from its own θ row
+    (noise on, so a row read at the wrong level would show); the dataset
+    verifies."""
     import subprocess
     import sys
     script = f"""
@@ -269,6 +273,30 @@ assert g.n_edges == fit.E
 src = np.asarray(g.src)
 assert src.max() < 2 ** fit.n
 assert sorted(np.unique(src >> (fit.n - 2)).tolist()) == [0, 1, 2, 3]
+
+# step 0 drawn again from the same uniforms by the law, in numpy
+import jax
+from repro.core.distributed_gen import step_seeds
+th = np.asarray(job.scheduler.thetas, np.float32)
+shard = ds.load_shard(0)
+epd = 8192 // 4
+for d, seed in enumerate(step_seeds(0, 0, 4)):
+    keys = jax.random.split(jax.random.fold_in(jax.random.PRNGKey(0),
+                                               int(seed)), fit.n)
+    s_id, d_id = d, 0
+    for ell in range(fit.n):
+        u = np.asarray(jax.random.uniform(keys[ell], (epd,)))
+        a, b, c, dd = th[ell]
+        if ell < 2:
+            sb = (d >> (1 - ell)) & 1
+            p0 = a / (a + b) if sb == 0 else c / (c + dd)
+            d_id = d_id * 2 + (u >= p0)
+        else:
+            s_id = s_id * 2 + (u >= a + b)
+            d_id = d_id * 2 + (((u >= a) & (u < a + b)) | (u >= a + b + c))
+    rows = slice(d * epd, (d + 1) * epd)
+    np.testing.assert_array_equal(shard.src[rows], s_id)
+    np.testing.assert_array_equal(shard.dst[rows], d_id)
 print("multidevice ok")
 """
     env = dict(os.environ, PYTHONPATH="src")
@@ -293,8 +321,10 @@ def test_device_steps_mode(tmp_path):
 
 def test_device_steps_compile_and_fetch_spans_nest_in_the_step(tmp_path):
     """The mesh step compiles inside the first ``struct.device_step``
-    (booked as ``compile.*`` spans there, no set-up span pretends to
-    time it), and each step's copy back is one ``struct.fetch``."""
+    (booked as ``compile.*`` spans under its ``struct.dispatch``, no
+    set-up span pretends to time it), each step's call is one
+    ``struct.dispatch`` and its copy back one ``struct.fetch``, and
+    ``struct.steps`` counts the steps."""
     from repro.obs import MemorySink, MetricsRegistry, Tracer
 
     sink, metrics = MemorySink(), MetricsRegistry()
@@ -306,11 +336,16 @@ def test_device_steps_compile_and_fetch_spans_nest_in_the_step(tmp_path):
     steps = sink.spans("struct.device_step")
     assert len(steps) == 2 and not sink.spans("struct.compile")
     backend = sink.spans("compile.backend")
-    assert any(by_id[e["parent"]]["name"] == "struct.device_step"
+    assert any(by_id[e["parent"]]["name"] == "struct.dispatch"
                for e in backend)
+    for name in ("struct.dispatch", "struct.fetch"):
+        assert [by_id[e["parent"]]["name"] for e in sink.spans(name)] \
+            == ["struct.device_step"] * 2, name
     fetches = sink.spans("struct.fetch")
-    assert [by_id[e["parent"]]["name"] for e in fetches] \
-        == ["struct.device_step"] * 2
+    dispatches = sink.spans("struct.dispatch")
+    assert all(d["ts"] + d["dur"] <= f["ts"]
+               for d, f in zip(dispatches, fetches))
+    assert metrics.counter("struct.steps").value == 2
     assert sorted(e["args"]["shard"] for e in fetches) == sorted(
         r.shard_id for r in manifest.shards if r.status == "done")
     assert metrics.counter("struct.bytes_fetched").value == sum(

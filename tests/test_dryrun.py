@@ -130,7 +130,8 @@ def test_distributed_generation_executes():
     with mesh:
         src, dst = device_generate(thetas, seeds, n, m, 1024, mesh)
     src = np.asarray(src).reshape(4, -1)
-    prefixes = np.unique(src >> n)
-    assert sorted(prefixes.tolist()) == [0, 1, 2, 3], prefixes
+    # the top 2 of the n src levels are the device index
+    assert (src >> (n - 2) == np.arange(4)[:, None]).all()
+    assert np.asarray(dst).max() < 2 ** m
     print("prefixes ok")
     """)

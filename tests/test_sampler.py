@@ -523,9 +523,21 @@ def test_legacy_manifest_without_backend_resumes_as_xla(tmp_path):
     assert m.is_complete() and m.backend == "xla"
     # device_steps: marker recorded, explicit sampler backend refused
     from repro.datastream.service import _DEVICE_STREAM
+    assert _DEVICE_STREAM == "device_descend_v3"
     job = DatasetJob(fit, str(tmp_path / "dev"), shard_edges=4096,
                      seed=0, mode="device_steps")
     assert job.backend == _DEVICE_STREAM
+    # a dataset of the previous device stream refuses to resume
+    job.run(max_shards=1)
+    path = os.path.join(str(tmp_path / "dev"), "manifest.json")
+    with open(path) as f:
+        raw = json.load(f)
+    raw["backend"] = "device_descend_v2"
+    with open(path, "w") as f:
+        json.dump(raw, f)
+    with pytest.raises(ValueError, match="backend"):
+        DatasetJob(fit, str(tmp_path / "dev"), shard_edges=4096, seed=0,
+                   mode="device_steps").resume()
     with pytest.raises(ValueError, match="device_steps"):
         DatasetJob(fit, str(tmp_path / "dev2"), shard_edges=4096,
                    seed=0, mode="device_steps", backend="pallas_bits")
